@@ -46,6 +46,7 @@ from serve_bench import build_model, warm_engine  # noqa: E402
 from repro.api import Gateway  # noqa: E402
 from repro.api.protocol import DONE_SENTINEL  # noqa: E402
 from repro.fleet import FleetRouter  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.quant.qarray import (dequant_counters,  # noqa: E402
                                 reset_dequant_counters)
 from repro.serve import (PagedServeEngine, SamplingParams,  # noqa: E402
@@ -514,6 +515,7 @@ def main():
     ap.add_argument("--out", default="api_bench",
                     help="results/benchmarks/<out>.json basename")
     args = ap.parse_args()
+    enable_compile_cache()
 
     import jax
     from repro.quant import quantize_params

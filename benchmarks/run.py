@@ -8,6 +8,7 @@ from . import (fig2_profiling, fig7_alpha_sweep, fig8_token_scaling,
                fig9_slm_suite, fig10_edge_comparison, table1_cim_comparison,
                kernel_bench)
 from .common import csv_row, save_json
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -16,6 +17,7 @@ def main() -> None:
                     help="reduced GA budgets (CI)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     jobs = {

@@ -35,6 +35,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(__file__))
 from common import save_json  # noqa: E402
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import DecoderLM, ModelConfig, init_params  # noqa: E402
 from repro.serve import PagedServeEngine, ServeRequest  # noqa: E402
 from repro.serve.telemetry import Telemetry  # noqa: E402
@@ -266,6 +267,7 @@ def main():
                          "recurrent/hybrid family (writes "
                          "serve_bench_<family>.json)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.family != "dense":
         model, params = build_model(args.scale, args.family)

@@ -32,6 +32,7 @@ from serve_bench import warm_engine  # noqa: E402
 from repro.core import EdgeCIMSimulator, SpecKnob  # noqa: E402
 from repro.core.hw import HWConfig  # noqa: E402
 from repro.core.workload import make_dense_spec  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import DecoderLM, ModelConfig, init_params  # noqa: E402
 from repro.serve import PagedServeEngine, ServeRequest  # noqa: E402
 from repro.spec import SpecConfig  # noqa: E402
@@ -106,6 +107,7 @@ def main():
     ap.add_argument("--drafters", nargs="+",
                     default=["ngram", "model", "self"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     model, params = build_model(args.scale, args.layers)
     # draft model: same family, 1 layer and half width (~8x fewer params)

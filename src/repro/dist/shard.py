@@ -21,14 +21,14 @@ from .axes import MeshRules, _axis_size, sanitize_pspec
 _ctx = threading.local()
 
 
-def serve_mesh(tp: int) -> Mesh:
-    """1-D ("model",) mesh over the first `tp` local devices — the mesh
-    one TP-sharded serve engine runs on.  Replicas may share the same
-    devices (data parallelism is the fleet's job, not the mesh's).
+def serve_mesh(tp: int, devices=None) -> Mesh:
+    """1-D ("model",) mesh over `devices` (default: the first `tp` local
+    devices) — the mesh one TP-sharded serve engine runs on.  The
+    launcher gives each replica its own devices when there are enough.
     Raises with the host-mesh escape hatch when the platform exposes
     fewer devices than `tp`."""
     import numpy as np
-    devs = jax.devices()
+    devs = list(devices) if devices is not None else jax.devices()
     if tp < 1:
         raise ValueError(f"tp must be >= 1, got {tp}")
     if len(devs) < tp:
@@ -46,13 +46,14 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def _current() -> Optional[Tuple[Mesh, MeshRules]]:
+def current_mesh_rules() -> Optional[Tuple[Mesh, MeshRules]]:
+    """The (mesh, rules) of the innermost `use_mesh_rules`, or None."""
     return getattr(_ctx, "mesh_rules", None)
 
 
 @contextlib.contextmanager
 def use_mesh_rules(mesh: Mesh, rules: MeshRules):
-    prev = _current()
+    prev = current_mesh_rules()
     _ctx.mesh_rules = (mesh, rules)
     try:
         yield
@@ -62,7 +63,7 @@ def use_mesh_rules(mesh: Mesh, rules: MeshRules):
 
 def constrain(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     """Sharding-constrain `x` by logical axis names (no-op w/o context)."""
-    cur = _current()
+    cur = current_mesh_rules()
     if cur is None:
         return x
     mesh, rules = cur

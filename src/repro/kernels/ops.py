@@ -1,10 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On the CPU container (no TPU backend) the kernels execute in
-interpret=True mode; the same call sites compile to real Mosaic kernels on
-TPU.  `qmatmul` additionally falls back to the pure-jnp reference when
-shapes are not tile-aligned (ragged edges) so model code can call it
-unconditionally.
+Off the TPU the kernels execute in interpret mode (and the serve path
+takes the pure-jnp references); the same call sites compile to real
+Mosaic kernels on the TPU.  Each route is chosen from what the code can
+observe — the backend and the shapes the kernels accept — so model code
+can call these unconditionally.
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.quant.qarray import QTensor, count_dequant, maybe_dequantize
 
-from .cim_gemv import cim_gemv
+from .cim_gemv import cim_gemv, tile_ok
 from .flash_decode import flash_decode
 from .paged_flash_decode import paged_flash_decode, paged_flash_verify
 from .ref import (ref_flash_decode, ref_paged_decode, ref_paged_verify,
@@ -27,10 +27,54 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _tile_ok(qt: QTensor) -> bool:
-    K, N = qt.orig_shape[0], qt.orig_shape[-1]
-    return (qt.ndim == 2 and qt.axis == -2 and K % qt.group == 0
-            and N % 128 == 0 and K % 256 == 0)
+def _tile_ok(qt: QTensor, rows: int, col_shards: int = 1) -> bool:
+    """A packed weight the Pallas GEMV kernels accept (`cim_gemv.tile_ok`):
+    2-D as traced (a scanned layer's slice counts), grouped along axis -2,
+    with each of `col_shards` column shards kernel-sized.  Sizes come
+    from the DATA array: under lax.scan the static orig_shape keeps the
+    stacked-layer dim."""
+    if qt.data.ndim != 2 or qt.axis != -2:
+        return False
+    K = qt.data.shape[0] * (2 if qt.bits == 4 else 1)
+    N = qt.data.shape[1]
+    return N % col_shards == 0 and tile_ok(K, N // col_shards, qt.group,
+                                           rows)
+
+
+def _mesh_rules():
+    """(mesh, rules) of an active multi-device serve mesh, else None."""
+    from repro.dist.shard import current_mesh_rules
+    cur = current_mesh_rules()
+    return cur if cur is not None and cur[0].size > 1 else None
+
+
+def _tp_shards() -> int:
+    """Devices the logical "tp" axis splits over in the active mesh."""
+    cur = _mesh_rules()
+    if cur is None:
+        return 1
+    from repro.dist.axes import _axis_size
+    return _axis_size(cur[0], cur[1].get("tp"))
+
+
+def _per_shard(fn, args, axes, out_axes):
+    """Call a Pallas kernel once per device of the active serve mesh.
+
+    A Mosaic kernel cannot be partitioned by XLA, so under a multi-device
+    `use_mesh_rules` context it runs in shard_map over the logical axes
+    tensor parallelism already split (kv heads, FFN columns): each device
+    computes its own heads or columns, exactly the work GSPMD assigns the
+    surrounding graph.  Without such a mesh the kernel is called as is."""
+    cur = _mesh_rules()
+    if cur is None:
+        return fn(*args)
+    from repro.dist import sanitize_pspec
+    mesh, rules = cur
+    specs = [sanitize_pspec(rules.pspec(a), x.shape, mesh)
+             for a, x in zip(axes, args)]
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=rules.pspec(out_axes),
+                         check_vma=False)(*args)
 
 
 def qmatmul(x: jax.Array, w: Any) -> jax.Array:
@@ -39,7 +83,7 @@ def qmatmul(x: jax.Array, w: Any) -> jax.Array:
         return x @ w
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if _tile_ok(w) and x2.shape[0] <= 1024:
+    if _tile_ok(w, x2.shape[0]):
         count_dequant("fused_dequant")
         out = cim_gemv(x2, w.data, w.scales, bits=w.bits, group=w.group,
                        interpret=_interpret())
@@ -59,22 +103,6 @@ def qmatmul_xla(x: jax.Array, w: Any) -> jax.Array:
     return ref_qmatmul_fused(x, w)
 
 
-def qmatmul_fused(x: jax.Array, w: Any) -> jax.Array:
-    """Serve-path x @ W: `cim_gemv` Pallas kernel on TPU when the packed
-    weight is tile-aligned and the row count is decode-sized, the fused
-    grouped-einsum reference otherwise.  Either way the float weight is
-    never materialized."""
-    if not isinstance(w, QTensor):
-        return x @ w
-    lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
-    if not _interpret() and _tile_ok(w) and x2.shape[0] <= 1024:
-        count_dequant("fused_dequant")
-        out = cim_gemv(x2, w.data, w.scales, bits=w.bits, group=w.group)
-        return out.reshape(*lead, w.orig_shape[-1])
-    return ref_qmatmul_fused(x, w)
-
-
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      pos: jax.Array, window: int = 0, attn_cap: float = 0.0,
                      use_kernel: bool = True) -> jax.Array:
@@ -91,6 +119,26 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(b, g, qpk, hd)
 
 
+_POOL = (None, "tp", None, None)          # (n_pages, g, ps, hd)
+_SCALES = (None, "tp", None)              # (n_pages, g, ps)
+_TABLES, _LENGTHS = (None, None), (None,)
+
+
+def _paged_attention(kernel, q, q_axes, k_pages, v_pages, tables, lengths,
+                     window, attn_cap, k_scales, v_scales):
+    args = [q, k_pages, v_pages, tables, lengths]
+    axes = [q_axes, _POOL, _POOL, _TABLES, _LENGTHS]
+    if k_scales is not None:
+        args += [k_scales, v_scales]
+        axes += [_SCALES, _SCALES]
+
+    def fn(q, k, v, tab, ln, ks=None, vs=None):
+        return kernel(q, k, v, tab, ln, window=window, attn_cap=attn_cap,
+                      interpret=_interpret(), k_scales=ks, v_scales=vs)
+
+    return _per_shard(fn, args, axes, q_axes)
+
+
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, tables: jax.Array,
                            lengths: jax.Array, window: int = 0,
@@ -98,8 +146,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            use_kernel: bool = None,
                            k_scales: jax.Array = None,
                            v_scales: jax.Array = None) -> jax.Array:
-    """Paged decode attention: q (b,g,qpk,hd), pools (n_pages,ps,g,hd),
-    tables (b,max_pages), lengths (b,) -> (b,g,qpk,hd).
+    """Paged decode attention: q (b,g,qpk,hd), head-major pools
+    (n_pages,g,ps,hd), tables (b,max_pages), lengths (b,) -> (b,g,qpk,hd).
 
     Routes to the Pallas block-table kernel on TPU (the gather never
     materializes); the pure-jnp gather reference is the lowering path
@@ -112,10 +160,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     if not use_kernel:
         return ref_paged_decode(q, k_pages, v_pages, tables, lengths,
                                 window, attn_cap, k_scales, v_scales)
-    return paged_flash_decode(q, k_pages, v_pages, tables, lengths,
-                              window=window, attn_cap=attn_cap,
-                              interpret=_interpret(),
-                              k_scales=k_scales, v_scales=v_scales)
+    return _paged_attention(paged_flash_decode, q, (None, "tp", None, None),
+                            k_pages, v_pages, tables, lengths, window,
+                            attn_cap, k_scales, v_scales)
 
 
 def paged_verify_attention(q: jax.Array, k_pages: jax.Array,
@@ -139,24 +186,37 @@ def paged_verify_attention(q: jax.Array, k_pages: jax.Array,
     if not use_kernel:
         return ref_paged_verify(q, k_pages, v_pages, tables, lengths,
                                 window, attn_cap, k_scales, v_scales)
-    return paged_flash_verify(q, k_pages, v_pages, tables, lengths,
-                              window=window, attn_cap=attn_cap,
-                              interpret=_interpret(),
-                              k_scales=k_scales, v_scales=v_scales)
+    return _paged_attention(paged_flash_verify, q,
+                            (None, None, "tp", None, None), k_pages,
+                            v_pages, tables, lengths, window, attn_cap,
+                            k_scales, v_scales)
 
 
-def swiglu(x: jax.Array, w_gate: Any, w_up: Any) -> jax.Array:
-    """Fused quantized SwiGLU: Pallas kernel when tile-aligned on TPU,
-    fused grouped-einsum reference otherwise — packed weights stay
-    integer on every route."""
-    if (isinstance(w_gate, QTensor) and isinstance(w_up, QTensor)
-            and not _interpret() and _tile_ok(w_gate) and _tile_ok(w_up)):
+def swiglu(x: jax.Array, w_gate: Any, w_up: Any,
+           use_kernel: bool = None) -> jax.Array:
+    """Fused quantized SwiGLU: the `swiglu_qgemv` Pallas kernel on TPU
+    when both packed weights are kernel-sized, the fused grouped-einsum
+    reference otherwise — packed weights stay integer on every route."""
+    lead = x.shape[:-1]
+    x2 = x.reshape(-1, x.shape[-1])
+    quant = isinstance(w_gate, QTensor) and isinstance(w_up, QTensor)
+    if use_kernel is None:
+        shards = _tp_shards()
+        use_kernel = (quant and not _interpret()
+                      and _tile_ok(w_gate, x2.shape[0], shards)
+                      and _tile_ok(w_up, x2.shape[0], shards))
+    if use_kernel:
         count_dequant("fused_dequant")
-        lead = x.shape[:-1]
-        x2 = x.reshape(-1, x.shape[-1])
-        out = swiglu_qgemv(x2, w_gate.data, w_gate.scales, w_up.data,
-                           w_up.scales, bits=w_gate.bits, group=w_gate.group)
-        return out.reshape(*lead, w_gate.orig_shape[-1])
+        cols = (None, "tp")                 # FFN columns split by TP
+
+        def fn(x, gd, gs, ud, us):
+            return swiglu_qgemv(x, gd, gs, ud, us, bits=w_gate.bits,
+                                group=w_gate.group, interpret=_interpret())
+
+        out = _per_shard(fn, [x2, w_gate.data, w_gate.scales, w_up.data,
+                              w_up.scales],
+                         [(None, None), cols, cols, cols, cols], cols)
+        return out.reshape(*lead, out.shape[-1])
     g = qmatmul_xla(x, w_gate).astype(jnp.float32)
     u = qmatmul_xla(x, w_up).astype(jnp.float32)
     return (g * jax.nn.sigmoid(g) * u).astype(x.dtype)

@@ -26,7 +26,7 @@ def _int_weight(qt: QTensor) -> jax.Array:
 
 def ref_qmatmul_fused(x: jax.Array, w, out_dtype=None) -> jax.Array:
     """x @ W with W held as integers end-to-end: per-group partial sums
-    contracted against the f16 scales — the CPU-backend image of the
+    contracted against the per-group scales — the CPU-backend image of the
     `cim_gemv` in-kernel dequant.  Never materializes the float weight
     (a whole-tensor `dequantize` would bump the `full_dequant` trace
     counter; this path bumps `fused_dequant` instead).
@@ -86,7 +86,7 @@ def ref_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     scores = scores / jnp.sqrt(jnp.float32(hd))
     if attn_cap:
         scores = attn_cap * jnp.tanh(scores / attn_cap)
-    k_pos = jnp.arange(S)
+    k_pos = jnp.arange(k.shape[1])
     mask = k_pos <= pos
     if window:
         mask = mask & (pos - k_pos < window)
@@ -95,15 +95,18 @@ def ref_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bgpk,bkgh->bgph", w.astype(v.dtype), v)
 
 
-def _gather_pages(pages: jax.Array, tables: jax.Array, b: int, S: int,
-                  scales: Optional[jax.Array] = None) -> jax.Array:
-    """Gather pool pages by block table; with `scales` (per-page INT8
-    quantized pool, scales (n_pages, ps, g)) dequantize ONLY the gathered
-    rows — the full pool never exists in float."""
-    x = pages[tables].reshape(b, S, *pages.shape[2:])
+def gather_pages(pages: jax.Array, tables: jax.Array,
+                 scales: Optional[jax.Array] = None) -> jax.Array:
+    """Gather head-major pool pages (n_pages, g, ps, hd) by block table
+    into a contiguous (b, S, g, hd) view; with `scales` (per-token INT8
+    pool, scales (n_pages, g, ps)) dequantize ONLY the gathered rows —
+    the full pool never exists in float."""
+    b, mp = tables.shape
+    _, g, ps, hd = pages.shape
+    x = pages[tables].transpose(0, 1, 3, 2, 4).reshape(b, mp * ps, g, hd)
     if scales is None:
         return x
-    s = scales[tables].reshape(b, S, *scales.shape[2:])
+    s = scales[tables].transpose(0, 1, 3, 2).reshape(b, mp * ps, g)
     return x.astype(jnp.float32) * s[..., None].astype(jnp.float32)
 
 
@@ -114,25 +117,22 @@ def ref_paged_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      v_scales: Optional[jax.Array] = None) -> jax.Array:
     """Paged single-token decode attention oracle (block-table gather).
 
-    q: (b, g, qpk, hd); k_pages, v_pages: (n_pages, page_size, g, hd);
+    q: (b, g, qpk, hd); k_pages, v_pages: (n_pages, g, page_size, hd);
     tables: (b, max_pages) int32 page ids (padded entries must be valid
     indices — they are masked out); lengths: (b,) int32 tokens valid per
     sequence INCLUSIVE of the current one.  With k_scales/v_scales the
-    pools are per-token INT8 (scales (n_pages, page_size, g) f16) and are
+    pools are per-token INT8 (scales (n_pages, g, page_size)) and are
     dequantized after the gather.  Returns (b, g, qpk, hd).
     """
-    b = q.shape[0]
     hd = q.shape[-1]
-    n_pg, ps = k_pages.shape[0], k_pages.shape[1]
-    S = tables.shape[1] * ps
-    k = _gather_pages(k_pages, tables, b, S, k_scales)
-    v = _gather_pages(v_pages, tables, b, S, v_scales)
+    k = gather_pages(k_pages, tables, k_scales)
+    v = gather_pages(v_pages, tables, v_scales)
     scores = jnp.einsum("bgph,bkgh->bgpk", q, k.astype(q.dtype),
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))
     if attn_cap:
         scores = attn_cap * jnp.tanh(scores / attn_cap)
-    k_pos = jnp.arange(S)
+    k_pos = jnp.arange(k.shape[1])
     mask = k_pos[None, :] < lengths[:, None]
     if window:
         mask = mask & ((lengths[:, None] - 1) - k_pos[None, :] < window)
@@ -155,18 +155,16 @@ def ref_paged_verify(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     the window, unlike `ref_paged_decode`).  Intra-window causal mask:
     query j sees k_pos <= lengths[i] + j.  Returns (b, s, g, qpk, hd).
     """
-    b, s = q.shape[0], q.shape[1]
+    s = q.shape[1]
     hd = q.shape[-1]
-    ps = k_pages.shape[1]
-    S = tables.shape[1] * ps
-    k = _gather_pages(k_pages, tables, b, S, k_scales)
-    v = _gather_pages(v_pages, tables, b, S, v_scales)
+    k = gather_pages(k_pages, tables, k_scales)
+    v = gather_pages(v_pages, tables, v_scales)
     scores = jnp.einsum("bqgph,bkgh->bgpqk", q, k.astype(q.dtype),
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))
     if attn_cap:
         scores = attn_cap * jnp.tanh(scores / attn_cap)
-    k_pos = jnp.arange(S)
+    k_pos = jnp.arange(k.shape[1])
     q_pos = lengths[:, None] + jnp.arange(s)[None, :]           # (b, s)
     mask = k_pos[None, None, :] <= q_pos[:, :, None]            # (b, s, S)
     if window:

@@ -9,10 +9,68 @@ per-lane StateArena slots.  Prefix caching and speculative decoding are
 attention-only capabilities — `--spec` on a recurrent-state family is a
 hard error, and `--no-prefix-cache` is auto-implied for hybrid/
 recurrent families (see `check_capabilities`).
+
+`--smoke` serves the arch's small config in float32; without it the
+arch runs at its published widths in its own dtype (bf16), with random
+weights from a seed.  On a CPU backend (the tests) the Pallas kernels
+run in interpret mode and the serve path takes their jnp references;
+on a TPU the same code runs the compiled kernels.  `chip_smoke.py` at
+the repo root drives this launcher's `load_model`/`build_engines` on
+the chip: `python chip_smoke.py` serves qwen2.5-3b at full width on one
+chip, and `python chip_smoke.py --chips 4` checks two tp=2 replicas
+against one tp=1 engine on a four-chip host.
 """
 import argparse
 
 import numpy as np
+
+
+def load_model(arch: str, smoke: bool, seed: int = 0):
+    """(model, params) for `arch` with random weights from `seed`: the
+    smoke config in float32, or the full config at its published widths
+    in its own dtype."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config, get_smoke_config
+    from repro.models import DecoderLM, init_params
+    if smoke:
+        cfg = get_smoke_config(arch).replace(dtype="float32", remat=False)
+    else:
+        cfg = get_config(arch).replace(remat=False)
+    if not cfg.embed_inputs:
+        raise ValueError(f"{arch} takes frontend-stub embeddings; the "
+                         "token engine serves token-input archs")
+    model = DecoderLM(cfg)
+    # one program: eagerly, each leaf's f32 draw and its scaled copy sit
+    # beside the weights already built — about twice the model's bytes
+    # at peak, which a 3B model in bf16 cannot afford on a 16 GB chip
+    init = jax.jit(lambda key: init_params(
+        model.param_specs(), key,
+        dtype_override=jnp.float32 if smoke else None))
+    return model, init(jax.random.PRNGKey(seed))
+
+
+def build_engines(model, params, serve_cfg, spec=None, n=None):
+    """`n` (default `serve_cfg.replicas`) engines serving `params`.
+
+    The first engine quantizes float params when the config asks, and
+    later replicas adopt its packed tensors.  Replica i runs on devices
+    [i*tp, (i+1)*tp) of `jax.devices()` when there are enough for every
+    replica; otherwise all of them share the first tp devices."""
+    import jax
+    from repro.serve import PagedServeEngine
+    n = serve_cfg.replicas if n is None else n
+    tp = serve_cfg.tp
+    devs = jax.devices()
+    own = len(devs) >= n * tp
+    engines = []
+    for i in range(n):
+        eng = PagedServeEngine(
+            model, params, serve_cfg, spec=spec,
+            devices=devs[i * tp:(i + 1) * tp] if own else devs[:tp])
+        params = eng.params          # share (possibly packed) weights
+        engines.append(eng)
+    return engines
 
 
 def check_capabilities(model, spec_mode: str, no_prefix_cache: bool):
@@ -133,12 +191,12 @@ def main():
 
     import jax
     import jax.numpy as jnp
-    from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import DecoderLM, init_params
     from repro.quant import quantized_fraction
-    from repro.serve import (PagedServeEngine, SamplingParams, ServeConfig,
-                             ServeRequest)
+    from repro.serve import SamplingParams, ServeConfig, ServeRequest
 
+    enable_compile_cache()
     # --quant predates ServeConfig; keep it working as an alias
     precision = args.precision
     if args.quant is not None:
@@ -151,14 +209,11 @@ def main():
     if precision is None:
         precision = "int4"          # the paper's operating point
 
-    cfg = (get_smoke_config(args.arch) if args.smoke
-           else get_config(args.arch)).replace(dtype="float32", remat=False)
-    if not cfg.embed_inputs:
-        raise SystemExit(f"{args.arch} takes frontend-stub embeddings; the "
-                         "token engine serves token-input archs")
-    model = DecoderLM(cfg)
-    params = init_params(model.param_specs(), jax.random.PRNGKey(0),
-                         dtype_override=jnp.float32)
+    try:
+        model, params = load_model(args.arch, args.smoke)
+    except ValueError as e:
+        raise SystemExit(str(e))
+    cfg = model.cfg
 
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab, int(n)).astype(np.int32)
@@ -178,7 +233,8 @@ def main():
             draft = DecoderLM(dcfg)
             dparams = init_params(draft.param_specs(),
                                   jax.random.PRNGKey(7),
-                                  dtype_override=jnp.float32)
+                                  dtype_override=jnp.float32
+                                  if args.smoke else None)
             spec_cfg = SpecConfig(k=args.spec_k, drafter="model",
                                   draft_model=draft,
                                   draft_params=dparams,
@@ -208,14 +264,11 @@ def main():
         policy=args.policy, max_pending=args.max_pending,
         tp=args.tp)
 
-    def build_engine():
-        # the engine quantizes float params itself when the config says
-        # so; replicas then share the packed tensors (first engine
-        # captures them below so later builds skip re-quantizing)
-        return PagedServeEngine(model, params, serve_cfg, spec=spec_cfg)
-
-    eng = build_engine()
-    params = eng.params          # share (possibly packed) weights
+    # in gateway mode every replica is built; the offline sweep runs one
+    engines = build_engines(model, params, serve_cfg, spec=spec_cfg,
+                            n=args.replicas if args.gateway else 1)
+    eng = engines[0]
+    params = eng.params
     if serve_cfg.quantized():
         # report from the ENGINE's config: it pins auto-resolutions the
         # request couldn't know about (e.g. MLA degrades auto-int8 KV
@@ -227,10 +280,6 @@ def main():
         import asyncio
         from repro.api import Gateway
         from repro.fleet import FleetRouter
-        # replicas share params (read-only under jit): N engines cost N
-        # KV pools + N driver threads, not N copies of the weights
-        engines = [eng] + [build_engine()
-                           for _ in range(args.replicas - 1)]
         router = FleetRouter(engines)
         import sys
         access_log = (sys.stderr if args.access_log == "-"

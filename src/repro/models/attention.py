@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from .common import (FSDP, NONE, TP, ParamSpec, apply_rope, rms_norm,
                      rope_tables, softcap)
 from repro.kernels.ops import qmatmul_xla as qmm
+from repro.kernels.ref import gather_pages
 from repro.quant.qarray import maybe_dequantize as deq
 from .config import ModelConfig
 
@@ -234,25 +235,31 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
 
 
 def _page_scatter(pool: jax.Array, vals: jax.Array, tables: jax.Array,
-                  slots: jax.Array, n_new: jax.Array) -> jax.Array:
+                  slots: jax.Array, n_new: jax.Array,
+                  head_major: bool = False) -> jax.Array:
     """Write per-token rows into a paged pool.
 
-    pool: (n_pages, page_size, ...); vals: (b, s, ...); tables:
-    (b, max_pages); slots: (b, s) absolute positions; n_new: (b,) valid
-    new tokens per sequence (padding lanes write out-of-bounds and drop).
+    pool: (n_pages, page_size, ...), or with `head_major` (n_pages, g,
+    page_size, ...); vals: (b, s, ...) (b, s, g, ... when head-major);
+    tables: (b, max_pages); slots: (b, s) absolute positions; n_new: (b,)
+    valid new tokens per sequence (padding lanes write out-of-bounds and
+    drop).
     """
     b, s = vals.shape[0], vals.shape[1]
-    n_pages, ps = pool.shape[0], pool.shape[1]
+    n_pages, ps = pool.shape[0], pool.shape[2 if head_major else 1]
     page = tables[jnp.arange(b)[:, None], slots // ps]           # (b, s)
     page = jnp.where(jnp.arange(s)[None, :] < n_new[:, None], page, n_pages)
     off = slots % ps
-    return pool.at[page, off].set(vals.astype(pool.dtype), mode="drop")
+    # head-major: the advanced indices straddle the head axis, so the
+    # indexed view is (b, s, g, ...) — vals' own layout
+    idx = (page, slice(None), off) if head_major else (page, off)
+    return pool.at[idx].set(vals.astype(pool.dtype), mode="drop")
 
 
 def _quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Per-(token, kv-head) symmetric INT8: x (b, s, g, hd) -> (values
-    rounded to [-127, 127] still in float, scales (b, s, g) f16).  The
-    STORED f16 scale is what divides, so pool int8 x pool scale
+    rounded to [-127, 127] still in float, scales (b, s, g) f16-rounded).
+    The STORED scale is what divides, so pool int8 x pool scale
     round-trips without a second rounding."""
     absmax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
     scale = (jnp.maximum(absmax, 1e-8) / 127.0).astype(jnp.float16)
@@ -269,7 +276,8 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
 
     x: (b, s, d) — s == 1 is decode, s > 1 a prefill chunk (right-padded;
     `n_new[i]` of the s tokens are real).  cache {k, v}:
-    (n_pages, page_size, g, hd) page pools shared by the whole batch;
+    head-major (n_pages, g, page_size, hd) page pools shared by the whole
+    batch;
     tables: (b, max_pages) int32; lengths: (b,) tokens already cached.
     Per-sequence positions — no shared `pos` scalar, so one sequence's
     prefill can never clobber another's rows (the dense engine's
@@ -284,8 +292,6 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
     """
     b, s, _ = x.shape
     hd, g, qpk = cfg.hd(), cfg.n_kv_heads, cfg.q_per_kv()
-    ps = cache["k"].shape[1]
-    S = tables.shape[1] * ps
     q, k, v = _qkv(p, cfg, x)
 
     theta_local = cfg.rope_theta_local or cfg.rope_theta
@@ -301,14 +307,14 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
         # (COW/fork/trim move them with their K/V pages for free)
         kq, ks = _quantize_kv_rows(k)
         vq, vs = _quantize_kv_rows(v)
-        ck = _page_scatter(cache["k"], kq, tables, slots, n_new)
-        cv = _page_scatter(cache["v"], vq, tables, slots, n_new)
-        cks = _page_scatter(cache["k_scale"], ks, tables, slots, n_new)
-        cvs = _page_scatter(cache["v_scale"], vs, tables, slots, n_new)
+        ck = _page_scatter(cache["k"], kq, tables, slots, n_new, True)
+        cv = _page_scatter(cache["v"], vq, tables, slots, n_new, True)
+        cks = _page_scatter(cache["k_scale"], ks, tables, slots, n_new, True)
+        cvs = _page_scatter(cache["v_scale"], vs, tables, slots, n_new, True)
         out_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
     else:
-        ck = _page_scatter(cache["k"], k, tables, slots, n_new)
-        cv = _page_scatter(cache["v"], v, tables, slots, n_new)
+        ck = _page_scatter(cache["k"], k, tables, slots, n_new, True)
+        cv = _page_scatter(cache["v"], v, tables, slots, n_new, True)
         cks = cvs = None
         out_cache = {"k": ck, "v": cv}
     total = lengths + n_new                                      # (b,)
@@ -339,16 +345,9 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
         return qmm(out, p["wo"]), out_cache
 
     # chunk path: gather the sequence's pages back to a contiguous view
-    if quant_kv:
-        kg = (ck[tables].astype(jnp.float32)
-              * cks[tables][..., None].astype(jnp.float32)
-              ).reshape(b, S, g, hd)
-        vg = (cv[tables].astype(jnp.float32)
-              * cvs[tables][..., None].astype(jnp.float32)
-              ).reshape(b, S, g, hd)
-    else:
-        kg = ck[tables].reshape(b, S, g, hd)
-        vg = cv[tables].reshape(b, S, g, hd)
+    kg = gather_pages(ck, tables, cks)
+    vg = gather_pages(cv, tables, cvs)
+    S = kg.shape[1]
     qg = q.reshape(b, s, g, qpk, hd)
     scores = jnp.einsum("bqgph,bkgh->bgpqk", qg, kg.astype(qg.dtype),
                         preferred_element_type=jnp.float32) * scale
@@ -539,11 +538,14 @@ def paged_cache_spec(cfg: ModelConfig, n_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> Dict[str, jax.ShapeDtypeStruct]:
     """Shape/dtype of one layer's paged KV pool (shared by all sequences).
 
-    dtype == int8 requests the quantized pool layout: int8 K/V plus f16
-    per-(token, kv-head) scale pools keyed "k_scale"/"v_scale".  Every
-    leaf keeps the page axis first, so the allocator's page-copy (COW),
-    fork, and trim move scales together with their pages — the block
-    table stays the single source of truth.
+    K/V pools are head-major, (n_pages, g, page_size, hd), so the paged
+    kernels stream one head's page as a dense (page_size, hd) tile.
+    dtype == int8 requests the quantized pool layout: int8 K/V plus
+    per-(token, kv-head) scale pools (n_pages, g, page_size) keyed
+    "k_scale"/"v_scale", f32 holding f16-rounded values.  Every leaf keeps
+    the page axis first, so the allocator's page-copy (COW), fork, and
+    trim move scales together with their pages — the block table stays
+    the single source of truth.
     """
     if cfg.attn_kind == "mla":
         if dtype == jnp.int8:
@@ -558,12 +560,12 @@ def paged_cache_spec(cfg: ModelConfig, n_pages: int, page_size: int,
             "k_rope": jax.ShapeDtypeStruct((n_pages, page_size,
                                             m.qk_rope_head_dim), dtype),
         }
-    kv = jax.ShapeDtypeStruct((n_pages, page_size, cfg.n_kv_heads,
+    kv = jax.ShapeDtypeStruct((n_pages, cfg.n_kv_heads, page_size,
                                cfg.hd()), dtype)
     spec = {"k": kv, "v": kv}
     if dtype == jnp.int8:
-        sc = jax.ShapeDtypeStruct((n_pages, page_size, cfg.n_kv_heads),
-                                  jnp.float16)
+        sc = jax.ShapeDtypeStruct((n_pages, cfg.n_kv_heads, page_size),
+                                  jnp.float32)
         spec["k_scale"] = sc
         spec["v_scale"] = sc
     return spec

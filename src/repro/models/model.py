@@ -708,10 +708,10 @@ class DecoderLM:
             return {}
 
         def pool_axes(name, struct):
-            if len(struct.shape) == 4:          # (n_pages, ps, g, hd)
-                return (NONE, NONE, TP, NONE)
-            if name.endswith("_scale"):         # (n_pages, ps, g) INT8 scales
-                return (NONE, NONE, TP)
+            if len(struct.shape) == 4:          # (n_pages, g, ps, hd)
+                return (NONE, TP, NONE, NONE)
+            if name.endswith("_scale"):         # (n_pages, g, ps) INT8 scales
+                return (NONE, TP, NONE)
             return (NONE, NONE, NONE)           # (n_pages, ps, r) MLA latent
 
         pool_cfg = cfg

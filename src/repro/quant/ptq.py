@@ -29,6 +29,12 @@ QUANT_KEYS = {
 }
 
 
+# one fused program per leaf: eagerly, a stacked (layers, K, N) leaf
+# holds several f32 copies of itself at once, more than a 16 GB chip
+# has room for beside a 3B model's float weights
+_quantize_jit = jax.jit(quantize, static_argnames=("bits", "group", "axis"))
+
+
 def _leaf_name(path) -> str:
     for p in reversed(path):
         if hasattr(p, "key"):
@@ -78,7 +84,7 @@ def _quantize_leaf(name: str, x: Any, bits: int, group: int,
         # quantize() would assert/divide by zero on it
         _skip_leaf(name, K)
         return x
-    return quantize(x, bits=bits, group=g, axis=axis)
+    return _quantize_jit(x, bits=bits, group=g, axis=axis)
 
 
 def quantize_params(params: Any, bits: int = 4, group: int = 128,
@@ -118,7 +124,7 @@ def quantize_structs(spec_tree: Any, bits: int = 4, group: int = 128,
         return QTensor(
             data=_jax.ShapeDtypeStruct(tuple(dshape),
                                        jnp.uint8 if bits == 4 else jnp.int8),
-            scales=_jax.ShapeDtypeStruct(tuple(sshape), jnp.float16),
+            scales=_jax.ShapeDtypeStruct(tuple(sshape), jnp.float32),
             bits=bits, group=g, axis=axis - len(shape),
             orig_shape=shape)
 

@@ -53,7 +53,7 @@ class QTensor:
     packs two consecutive `axis` entries per uint8 byte, in place:
     data.shape == orig_shape except axis dim halved (bits=4)."""
     data: jax.Array          # int8 (bits=8) or uint8 packed pairs (bits=4)
-    scales: jax.Array        # orig_shape with axis dim = K/group, f16
+    scales: jax.Array        # orig_shape with axis dim = K/group, f32
     bits: int
     group: int
     axis: int                # NEGATIVE (from the end): slice-invariant under
@@ -80,8 +80,8 @@ class QTensor:
 
     def nbytes_packed(self) -> int:
         import numpy as np
-        return int(np.prod(self.data.shape)) + 2 * int(
-            np.prod(self.scales.shape))
+        return (int(np.prod(self.data.shape))
+                + self.scales.dtype.itemsize * int(np.prod(self.scales.shape)))
 
     def dequantize(self, dtype=jnp.bfloat16) -> jax.Array:
         return dequantize(self, dtype)
@@ -105,9 +105,11 @@ def quantize(w: jax.Array, bits: int = 4, group: int = INT4_GROUP,
     scale = jnp.maximum(absmax, 1e-8) / qmax
     q = jnp.clip(jnp.round(wg / scale), -qmax - 1, qmax)
     q = q.reshape(K, *rest).astype(jnp.int8)
-    # f16 scales: bf16's 8-bit mantissa costs up to 0.5*scale of
-    # extra INT8 error; f16 (10-bit) keeps it <6% (same 16-bit storage)
-    scales = jnp.moveaxis(scale[:, 0].astype(jnp.float16), 0, axis)
+    # f16-rounded scales: bf16's 8-bit mantissa costs up to 0.5*scale of
+    # extra INT8 error; f16 (10-bit) keeps it <6%.  Stored as f32, the
+    # dtype the TPU kernels can load (the values stay f16-exact)
+    scales = jnp.moveaxis(scale[:, 0].astype(jnp.float16)
+                          .astype(jnp.float32), 0, axis)
     if bits == 4:
         assert K % 2 == 0
         lo = (q[0::2].astype(jnp.int32) + 8)

@@ -122,7 +122,8 @@ class PagedServeEngine:
                  eos_id=_UNSET, seed=_UNSET,
                  spec: Optional[Any] = None,
                  prefix_cache=_UNSET,
-                 clock=time.monotonic):
+                 clock=time.monotonic,
+                 devices: Optional[List[Any]] = None):
         legacy = {k: v for k, v in [
             ("max_batch", max_batch), ("max_seq", max_seq),
             ("page_size", page_size), ("n_pages", n_pages),
@@ -162,14 +163,15 @@ class PagedServeEngine:
         prefill_chunk, eos_id = config.prefill_chunk, config.eos_id
         seed, prefix_cache = config.seed, config.prefix_cache
         kv_dtype = config.resolved_kv_dtype()
-        # tensor parallelism: a ("model",) mesh of tp devices.  Raises
-        # here — not at first step — when tp does not divide the
-        # model's head/FFN dims or the backend lacks the devices.
+        # tensor parallelism: a ("model",) mesh of tp devices (the
+        # first tp of `devices`, else of jax.devices()).  Raises here —
+        # not at first step — when tp does not divide the model's
+        # head/FFN dims or the backend lacks the devices.
         self.mesh = None
         if config.tp > 1:
             from repro.dist import serve_mesh
             model.validate_tp(config.tp)
-            self.mesh = serve_mesh(config.tp)
+            self.mesh = serve_mesh(config.tp, devices)
         if config.quantized() and not any(
                 isinstance(l, QTensor) for l in jax.tree_util.tree_leaves(
                     params, is_leaf=lambda x: isinstance(x, QTensor))):
@@ -216,6 +218,15 @@ class PagedServeEngine:
         self._state_shardings = None
         if self.mesh is not None:
             self._shard_runtime_state(state_specs)
+        elif devices is not None:
+            # one device: commit weights and state there, and every
+            # jitted step follows its committed arguments
+            from jax.sharding import SingleDeviceSharding
+            on = SingleDeviceSharding(devices[0])
+            self.params = jax.device_put(self.params, on)
+            self.cache.pools = jax.device_put(self.cache.pools, on)
+            if self.arena is not None:
+                self.arena.state = jax.device_put(self.arena.state, on)
         # prefix sharing: committed prompt pages live in a radix trie and
         # are adopted by later requests with the same prefix (see
         # prefix.py); allocation pressure evicts trie-only pages LRU
@@ -385,7 +396,8 @@ class PagedServeEngine:
         """Run one jitted step: flatten paged pools + arena slots into
         the unified cache dict (their key sets are disjoint by
         construction), split the returned state back.  Returns
-        (logits, graph seconds)."""
+        (logits, graph seconds) — the clock stops once the device has
+        produced the logits, not when the step was enqueued."""
         state = dict(self.cache.pools)
         if self.arena is not None:
             state.update(self.arena.state)
@@ -393,13 +405,14 @@ class PagedServeEngine:
         logits, state = fn(
             self.params, state, {"tokens": jnp.asarray(tokens)},
             jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(n_new))
+        jax.block_until_ready(logits)
+        dt = time.monotonic() - t0
         if self.mesh is not None:
             # one gathered host copy: every downstream consumer
             # (sampling, logprobs, verify walks) runs on identical
             # bytes regardless of tp — the byte-identity invariant
             # lives here
             logits = jax.device_get(logits)
-        dt = time.monotonic() - t0
         self._last_t0 = t0      # span start for tracer.complete()
         if self.arena is not None:
             self.arena.state = {k: state[k] for k in self.arena.keys}

@@ -77,9 +77,9 @@ def test_paged_flash_decode_sweep(page_size, max_pages, window, cap):
     n_pages = b * max_pages
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, g, qpk, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, page_size, g, hd)),
+    kp = jnp.asarray(rng.standard_normal((n_pages, g, page_size, hd)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page_size, g, hd)),
+    vp = jnp.asarray(rng.standard_normal((n_pages, g, page_size, hd)),
                      jnp.float32)
     tables = jnp.asarray(
         rng.permutation(n_pages).reshape(b, max_pages), jnp.int32)
@@ -105,9 +105,9 @@ def test_paged_flash_verify_sweep(s, page_size, max_pages, window, cap):
     n_pages = b * max_pages
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, s, g, qpk, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, page_size, g, hd)),
+    kp = jnp.asarray(rng.standard_normal((n_pages, g, page_size, hd)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page_size, g, hd)),
+    vp = jnp.asarray(rng.standard_normal((n_pages, g, page_size, hd)),
                      jnp.float32)
     tables = jnp.asarray(
         rng.permutation(n_pages).reshape(b, max_pages), jnp.int32)
@@ -125,8 +125,8 @@ def test_paged_verify_s1_matches_paged_decode():
     b, g, qpk, hd, ps, mp = 2, 2, 4, 64, 16, 8
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.standard_normal((b, 1, g, qpk, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((b * mp, ps, g, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((b * mp, ps, g, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((b * mp, g, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((b * mp, g, ps, hd)), jnp.float32)
     tables = jnp.arange(b * mp, dtype=jnp.int32).reshape(b, mp)
     lengths = jnp.asarray([17, 90], jnp.int32)
     dec = ref_paged_decode(q[:, 0], kp, vp, tables, lengths + 1)
@@ -142,14 +142,16 @@ def test_paged_decode_matches_dense_flash_decode():
     b, g, qpk, hd, ps, n_pg = 2, 2, 2, 32, 16, 16
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((b, g, qpk, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((b * n_pg // 2, ps, g, hd)),
+    kp = jnp.asarray(rng.standard_normal((b * n_pg // 2, g, ps, hd)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((b * n_pg // 2, ps, g, hd)),
+    vp = jnp.asarray(rng.standard_normal((b * n_pg // 2, g, ps, hd)),
                      jnp.float32)
     tables = jnp.arange(b * n_pg // 2, dtype=jnp.int32).reshape(b, -1)
     S = (n_pg // 2) * ps
-    kd = kp.reshape(b, S, g, hd)
-    vd = vp.reshape(b, S, g, hd)
+    kd = kp.reshape(b, n_pg // 2, g, ps, hd).swapaxes(2, 3).reshape(
+        b, S, g, hd)
+    vd = vp.reshape(b, n_pg // 2, g, ps, hd).swapaxes(2, 3).reshape(
+        b, S, g, hd)
     pos = jnp.int32(100)
     dense = ref_flash_decode(q, kd, vd, pos)
     paged = ops.paged_decode_attention(

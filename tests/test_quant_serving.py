@@ -95,16 +95,16 @@ def test_dequant_counters_classify_paths():
 # quantized paged KV: kernels vs refs (interpret mode)
 # ----------------------------------------------------------------------------
 def _quant_pools(rng, n_pages, ps, g, hd):
-    k = rng.normal(size=(n_pages, ps, g, hd)).astype(np.float32)
-    v = rng.normal(size=(n_pages, ps, g, hd)).astype(np.float32)
+    k = rng.normal(size=(n_pages, g, ps, hd)).astype(np.float32)
+    v = rng.normal(size=(n_pages, g, ps, hd)).astype(np.float32)
 
     def q(x):
         scale = (np.maximum(np.abs(x).max(-1), 1e-8) / 127.0
-                 ).astype(np.float16)       # the STORED scale is f16
+                 ).astype(np.float16)       # the STORED scale is f16-exact
         qi = np.clip(np.round(x / scale[..., None].astype(np.float32)),
                      -127, 127)
         return (jnp.asarray(qi, jnp.int8),
-                jnp.asarray(scale),
+                jnp.asarray(scale, jnp.float32),
                 jnp.asarray(qi * scale[..., None].astype(np.float32),
                             jnp.float32))
 

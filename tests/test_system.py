@@ -84,8 +84,9 @@ MINI_DRYRUN = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import SMOKE_SHAPES, build_cell
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     for arch in {archs}:
         for shape in {shapes}:
             cfg = get_smoke_config(arch)
@@ -97,8 +98,6 @@ MINI_DRYRUN = textwrap.dedent("""
                 compiled = jitted.lower(*cell.args).compile()
                 mem = compiled.memory_analysis()
                 cost = compiled.cost_analysis()
-            if isinstance(cost, list):   # older jax: one dict per device
-                cost = cost[0]
             assert float(cost.get("flops", 0)) > 0
             print("OK", arch, shape)
 """)

@@ -249,3 +249,71 @@ def test_tp2_page_conservation_random_interleavings():
         # shardings intact (out_shardings pins them step over step)
         pool_leaves = jax.tree_util.tree_leaves(eng.cache.pools)
         assert any(len(l.sharding.device_set) == 2 for l in pool_leaves)
+
+
+# ----------------------------------------------------------------------------
+# Pallas kernels under the serve mesh: one call per device (shard_map)
+# ----------------------------------------------------------------------------
+@pytest.mark.parametrize("quant_kv", [False, True])
+def test_tp2_paged_kernel_runs_per_shard(quant_kv):
+    """XLA cannot partition a Mosaic kernel, so under a tp=2 mesh the
+    paged kernel runs once per device on that device's kv heads; the
+    result must equal the unsharded gather reference."""
+    from repro.dist import use_mesh_rules
+    from repro.kernels import ops
+    from repro.kernels.ref import ref_paged_decode
+    mesh = serve_mesh(2)
+    rng = np.random.default_rng(3)
+    b, g, qpk, hd, ps, mp = 2, 2, 4, 64, 8, 4
+    q = jnp.asarray(rng.normal(size=(b, g, qpk, hd)), jnp.float32)
+    shape = (b * mp, g, ps, hd)
+    if quant_kv:
+        k = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.5, 1.5, shape[:3]) / 127,
+                         jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.5, 1.5, shape[:3]) / 127,
+                         jnp.float32)
+    else:
+        k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        ks = vs = None
+    tables = jnp.asarray(rng.permutation(b * mp).reshape(b, mp), jnp.int32)
+    lengths = jnp.asarray([5, 29], jnp.int32)
+
+    @jax.jit
+    def sharded(q, k, v, tables, lengths, ks, vs):
+        with use_mesh_rules(mesh, SERVE_RULES):
+            return ops.paged_decode_attention(q, k, v, tables, lengths,
+                                              use_kernel=True, k_scales=ks,
+                                              v_scales=vs)
+
+    out = sharded(q, k, v, tables, lengths, ks, vs)
+    ref = ref_paged_decode(q, k, v, tables, lengths, k_scales=ks,
+                           v_scales=vs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+def test_tp2_swiglu_kernel_runs_per_shard():
+    """The fused SwiGLU kernel under tp=2 computes each device's FFN
+    columns and leaves the output column-sharded for w_down."""
+    from repro.dist import use_mesh_rules
+    from repro.kernels import ops
+    from repro.kernels.ref import ref_swiglu_qgemv
+    from repro.quant.qarray import quantize
+    mesh = serve_mesh(2)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    wg = quantize(jax.random.normal(keys[0], (256, 512)) * 0.1, 4, 128)
+    wu = quantize(jax.random.normal(keys[1], (256, 512)) * 0.1, 4, 128)
+    x = jax.random.normal(keys[2], (3, 256))
+
+    @jax.jit
+    def sharded(x, wg, wu):
+        with use_mesh_rules(mesh, SERVE_RULES):
+            return ops.swiglu(x, wg, wu, use_kernel=True)
+
+    out = sharded(x, wg, wu)
+    assert out.sharding.spec == P(None, "model")
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref_swiglu_qgemv(x, wg, wu)),
+                               atol=1e-4)
