@@ -219,9 +219,10 @@ def step_args(eng, s: int):
 
 
 def decode_hlo(eng) -> str:
-    # the engine's own jitted step (a private handle: the smoke inspects
-    # the graph the engine serves with, not a copy of it)
-    return eng._step_fn.lower(*step_args(eng, 1)).compile().as_text()
+    # the engine's own jitted decode program (a private handle: the
+    # smoke inspects the graph the engine serves with, not a copy of it)
+    return eng._decode_fn.lower(eng.model,
+                                *step_args(eng, 1)).compile().as_text()
 
 
 def custom_calls(hlo: str, name: str) -> int:

@@ -15,6 +15,14 @@ nothing fires on completion.  `watch(req, on_done)` registers a
 request; after every step (and every job drain) the driver sweeps its
 watchlist and invokes `on_done(req)` exactly once when `req.done`
 flips — cancellations, rejections, and clean finishes all land there.
+
+Tracing: each loop iteration that steps the engine is a `driver_loop`
+span (a profiler step numbered by `steps`) holding `driver_job`,
+`sweep_done`, the engine's `engine_step` and `tap`.  Idle iterations
+record no loop span; a stretch with the engine idle is one ring-only
+`idle_wait` span, recorded when work arrives.  A job's wait in the
+inbox, from `call()` to its start, is the ring-only `driver_inbox`
+span.
 """
 from __future__ import annotations
 
@@ -23,7 +31,11 @@ import threading
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from repro.obs.trace import get_tracer
+from repro.obs.trace import NULL_SPAN, get_tracer
+
+# a queued job: the callable, its future, the enqueue time (while
+# tracing, else None) and the request ids it carries
+_Job = Tuple[Callable, Future, Optional[float], Optional[List[int]]]
 
 
 class EngineDriver:
@@ -35,7 +47,7 @@ class EngineDriver:
         never kills the serve loop."""
         self.engine = engine
         self._tap = tap
-        self._jobs: "queue.Queue[Tuple[Callable, Future]]" = queue.Queue()
+        self._jobs: "queue.Queue[_Job]" = queue.Queue()
         self._watch: List[Tuple[Any, Callable]] = []
         self._wake = threading.Event()
         self._stop = threading.Event()
@@ -69,19 +81,22 @@ class EngineDriver:
         return self._thread.is_alive()
 
     # -- cross-thread API ----------------------------------------------
-    def call(self, fn: Callable[[Any], Any]) -> Future:
+    def call(self, fn: Callable[[Any], Any],
+             rids: Optional[List[int]] = None) -> Future:
         """Schedule `fn(engine)` on the driver thread (between steps);
         returns a Future with its result or exception.  A job sent to a
         driver that already died (fatal step error / stopped) fails
-        immediately instead of hanging its caller forever."""
+        immediately instead of hanging its caller forever.  `rids`, the
+        request ids the job carries, label its `driver_inbox` span."""
         fut: Future = Future()
+        t_enq = self.tracer.now() if self.tracer.enabled else None
         with self._lock:
             if self._dead:
                 fut.set_exception(RuntimeError(
                     f"engine driver not running"
                     f"{f' ({self.error!r})' if self.error else ''}"))
                 return fut
-            self._jobs.put((fn, fut))
+            self._jobs.put((fn, fut, t_enq, rids))
         self._wake.set()
         return fut
 
@@ -96,7 +111,9 @@ class EngineDriver:
                 self._watch.append((r, on_done))
                 eids.append(r.eid)
             return eids
-        return self.call(job)
+        return self.call(job, rids=[getattr(r, "trace_id", -1)
+                                    for r in reqs]
+                         if self.tracer.enabled else None)
 
     def cancel(self, eids: List[int]) -> Future:
         """Cancel by engine id; resolves to the number actually
@@ -137,11 +154,15 @@ class EngineDriver:
 
     # -- loop -----------------------------------------------------------
     def _drain_jobs(self) -> None:
+        tr = self.tracer
         while True:
             try:
-                fn, fut = self._jobs.get_nowait()
+                fn, fut, t_enq, rids = self._jobs.get_nowait()
             except queue.Empty:
                 return
+            if tr.enabled and t_enq is not None:
+                tr.complete("driver_inbox", t_enq, tr.now() - t_enq,
+                            cat="driver", rids=rids)
             if not fut.set_running_or_notify_cancel():
                 continue
             try:
@@ -154,31 +175,55 @@ class EngineDriver:
     def _sweep_done(self) -> None:
         if not self._watch:
             return
-        still = []
-        for req, on_done in self._watch:
-            if req.done:
-                try:
-                    on_done(req)
-                except Exception:       # a dead client callback must
-                    pass                # never kill the serve loop
-            else:
-                still.append((req, on_done))
-        self._watch = still
+        with self.tracer.span("sweep_done", cat="driver"):
+            still = []
+            for req, on_done in self._watch:
+                if req.done:
+                    try:
+                        on_done(req)
+                    except Exception:   # a dead client callback must
+                        pass            # never kill the serve loop
+                else:
+                    still.append((req, on_done))
+            self._watch = still
 
-    def _run_tap(self) -> None:
+    def _run_tap(self, traced: bool = True) -> None:
         if self._tap is None:
             return
         try:
-            self._tap(self.engine)
+            with (self.tracer.span("tap", cat="driver") if traced
+                  else NULL_SPAN):
+                self._tap(self.engine)
         except Exception:       # a broken snapshot publisher must
             pass                # never take the engine down
 
     def _run(self) -> None:
         engine = self.engine
+        tr = self.tracer
+        idle_since = None       # tracer clock when the engine went idle
         while not self._stop.is_set():
-            self._drain_jobs()
-            self._sweep_done()
-            if engine.busy:
+            if not engine.busy:
+                # idle: no loop span, so a quiet server leaves the trace
+                # ring alone; one ring-only `idle_wait` covers the whole
+                # stretch, recorded once work arrives
+                self._drain_jobs()
+                self._sweep_done()
+                if not engine.busy:
+                    if idle_since is None and tr.enabled:
+                        idle_since = tr.now()
+                    self._run_tap(traced=False)
+                    self._wake.wait(self._idle_wait_s)
+                    self._wake.clear()
+                    continue
+            if idle_since is not None:
+                tr.complete("idle_wait", idle_since, tr.now() - idle_since,
+                            cat="driver")
+                idle_since = None
+            with tr.step_span("driver_loop", self.steps, cat="driver"):
+                self._drain_jobs()
+                self._sweep_done()
+                if not engine.busy:     # a drained cancel emptied it
+                    continue
                 try:
                     engine.step()
                 except BaseException as e:
@@ -196,15 +241,11 @@ class EngineDriver:
                         self.flight_path = recorder.dump(reason=repr(e))
                     break
                 self.steps += 1
-                # publish AFTER the step but BEFORE the next sweep
-                # fires done-watchers: by the time a client sees its
-                # completion, the fleet snapshot (incl. any prefix
-                # pages this step committed) is already visible
+                # publish AFTER the step but BEFORE the next sweep fires
+                # done-watchers: by the time a client sees its
+                # completion, the fleet snapshot (incl. any prefix pages
+                # this step committed) is already visible
                 self._run_tap()
-            else:
-                self._run_tap()
-                self._wake.wait(self._idle_wait_s)
-                self._wake.clear()
         # shutdown / fatal error: mark dead under the lock (new call()s
         # now fail fast), drain whatever was already queued, and fail
         # every request still in flight — a watcher left un-notified

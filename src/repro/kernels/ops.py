@@ -103,6 +103,13 @@ def qmatmul_xla(x: jax.Array, w: Any) -> jax.Array:
     return ref_qmatmul_fused(x, w)
 
 
+def projection(name: str, x: jax.Array, w: Any) -> jax.Array:
+    """`qmatmul_xla` under the named scope `name` (`q_proj`, `down_proj`,
+    ...), so each of its device ops says which projection it serves."""
+    with jax.named_scope(name):
+        return qmatmul_xla(x, w)
+
+
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      pos: jax.Array, window: int = 0, attn_cap: float = 0.0,
                      use_kernel: bool = True) -> jax.Array:

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from .common import (FSDP, NONE, TP, ParamSpec, apply_rope, rms_norm,
                      rope_tables, softcap)
-from repro.kernels.ops import qmatmul_xla as qmm
+from repro.kernels.ops import projection, qmatmul_xla as qmm
 from repro.kernels.ref import gather_pages
 from repro.quant.qarray import maybe_dequantize as deq
 from .config import ModelConfig
@@ -133,9 +133,9 @@ def _qkv(p: Params, cfg: ModelConfig, x: jax.Array
          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     b, s, _ = x.shape
     hd = cfg.hd()
-    q = qmm(x, p["wq"])
-    k = qmm(x, p["wk"])
-    v = qmm(x, p["wv"])
+    q = projection("q_proj", x, p["wq"])
+    k = projection("k_proj", x, p["wk"])
+    v = projection("v_proj", x, p["wv"])
     if cfg.qkv_bias:
         q = q + p["bq"]
         k = k + p["bk"]
@@ -172,7 +172,7 @@ def gqa_forward(p: Params, cfg: ModelConfig, x: jax.Array,
                              1.0 / math.sqrt(hd), cfg.attn_softcap,
                              unroll=cfg.unroll)
     out = out.reshape(b, s, cfg.n_heads * hd)
-    return qmm(out, p["wo"]), {"k": k, "v": v}
+    return projection("o_proj", out, p["wo"]), {"k": k, "v": v}
 
 
 def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
@@ -230,7 +230,8 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
                                       (0, pos, 0, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
                                       (0, pos, 0, 0))
-    out = qmm(out.reshape(b, 1, cfg.n_heads * hd), p["wo"])
+    out = projection("o_proj", out.reshape(b, 1, cfg.n_heads * hd),
+                     p["wo"])
     return out, {"k": ck, "v": cv}
 
 
@@ -331,7 +332,7 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
                                        cfg.attn_softcap,
                                        k_scales=cks, v_scales=cvs)
         out = out_g.reshape(b, 1, cfg.n_heads * hd).astype(x.dtype)
-        return qmm(out, p["wo"]), out_cache
+        return projection("o_proj", out, p["wo"]), out_cache
 
     if verify and not window:
         # speculative-verify fast path: all s window positions in one
@@ -342,7 +343,7 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
                                        cfg.attn_softcap,
                                        k_scales=cks, v_scales=cvs)
         out = out_g.reshape(b, s, cfg.n_heads * hd).astype(x.dtype)
-        return qmm(out, p["wo"]), out_cache
+        return projection("o_proj", out, p["wo"]), out_cache
 
     # chunk path: gather the sequence's pages back to a contiguous view
     kg = gather_pages(ck, tables, cks)
@@ -363,7 +364,7 @@ def gqa_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bgpqk,bkgh->bqgph", w.astype(vg.dtype), vg)
     out = out.reshape(b, s, cfg.n_heads * hd).astype(x.dtype)
-    return qmm(out, p["wo"]), out_cache
+    return projection("o_proj", out, p["wo"]), out_cache
 
 
 def mla_paged_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Dict,

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import ACTIVATIONS, EXPERT, FSDP, NONE, TP, ParamSpec
-from repro.kernels.ops import qmatmul_xla as qmm
+from repro.kernels.ops import projection, qmatmul_xla as qmm
 from repro.quant.qarray import maybe_dequantize as deq
 from .config import ModelConfig
 
@@ -43,15 +43,17 @@ def dense_ffn(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         # fused SwiGLU: one pass over the packed gate/up weights
         # (swiglu_qgemv Pallas kernel on TPU, fused grouped einsum on CPU)
         from repro.kernels.ops import swiglu
-        h = swiglu(x, p["w_gate"], p["w_up"])
-        return qmm(h, p["w_down"])
+        with jax.named_scope("gate_up"):
+            h = swiglu(x, p["w_gate"], p["w_up"])
+        return projection("down_proj", h, p["w_down"])
     act = ACTIVATIONS[cfg.ffn_act]
-    up = qmm(x, p["w_up"])
-    if cfg.ffn_gated:
-        h = act(qmm(x, p["w_gate"])) * up
-    else:
-        h = act(up)
-    return qmm(h, p["w_down"])
+    with jax.named_scope("gate_up"):
+        up = qmm(x, p["w_up"])
+        if cfg.ffn_gated:
+            h = act(qmm(x, p["w_gate"])) * up
+        else:
+            h = act(up)
+    return projection("down_proj", h, p["w_down"])
 
 
 # ----------------------------------------------------------------------------

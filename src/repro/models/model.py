@@ -110,11 +110,12 @@ class DecoderLM:
         cfg = self.cfg
         if cfg.embed_inputs:
             emb = params["embed"]
-            if isinstance(emb, QTensor):
-                h = dequant_rows(emb, inputs["tokens"],
-                                 cfg.activation_dtype())
-            else:
-                h = emb[inputs["tokens"]]
+            with jax.named_scope("embed"):
+                if isinstance(emb, QTensor):
+                    h = dequant_rows(emb, inputs["tokens"],
+                                     cfg.activation_dtype())
+                else:
+                    h = emb[inputs["tokens"]]
         else:
             h = inputs["embeddings"].astype(cfg.activation_dtype())
         if cfg.embed_scale:
@@ -126,18 +127,19 @@ class DecoderLM:
         h = apply_norm(params["ln_final"], cfg, h)
         w = params["embed"] if (cfg.tie_embeddings or "head" not in params) \
             else params["head"]
-        if isinstance(w, QTensor):
-            # fused grouped contraction: the packed vocab table is never
-            # materialized in float (the tied table groups along d — the
-            # contraction axis — exactly so this works)
-            from repro.kernels.ref import ref_qmatmul_fused
-            logits = ref_qmatmul_fused(h, w, out_dtype=jnp.float32)
-        elif cfg.tie_embeddings or "head" not in params:
-            logits = jnp.einsum("bsd,vd->bsv", h, w.astype(h.dtype),
-                                preferred_element_type=jnp.float32)
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype),
-                                preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            if isinstance(w, QTensor):
+                # fused grouped contraction: the packed vocab table is
+                # never materialized in float (the tied table groups
+                # along d — the contraction axis — exactly so this works)
+                from repro.kernels.ref import ref_qmatmul_fused
+                logits = ref_qmatmul_fused(h, w, out_dtype=jnp.float32)
+            elif cfg.tie_embeddings or "head" not in params:
+                logits = jnp.einsum("bsd,vd->bsv", h, w.astype(h.dtype),
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype),
+                                    preferred_element_type=jnp.float32)
         if cfg.final_softcap:
             logits = softcap(logits, cfg.final_softcap)
         return constrain(logits, "batch", None, "tp")

@@ -4,7 +4,8 @@ chrome_trace   Tracer events -> Chrome trace-event JSON (the format
                Perfetto / chrome://tracing load directly): one process,
                one track per recorded thread, "X" complete spans and
                "i" instants, args (request ids, lane lists, policy
-               scores) preserved per event.
+               scores) preserved per event, plus the span's `id` and
+               its enclosing span's `parent` in args.
 
 prometheus_text
                the gateway's /metrics JSON payload -> Prometheus text
@@ -52,8 +53,13 @@ def chrome_trace(tracer: Tracer,
             out["dur"] = ev["dur_s"] * 1e6
         if ev["ph"] == "i":
             out["s"] = "t"                  # instant scope: thread
-        if ev["args"]:
-            out["args"] = dict(ev["args"])
+        args = dict(ev["args"] or {})
+        if ev["id"] is not None:            # span nesting on its thread
+            args["id"] = ev["id"]
+        if ev["parent"] is not None:
+            args["parent"] = ev["parent"]
+        if args:
+            out["args"] = args
         events.append(out)
     events.insert(0, {"ph": "M", "name": "process_name", "pid": pid,
                       "tid": 0, "args": {"name": process_name}})

@@ -14,7 +14,8 @@ Design constraints, in order:
   disabled == free   every instrumentation site guards on the single
                      attribute read `tracer.enabled` before building
                      any args dict; `span()` on a disabled tracer
-                     returns one shared no-op context manager.
+                     returns one shared no-op context manager and
+                     builds no profiler annotation.
   no locks on the    each thread writes its OWN `collections.deque`
   hot path           (appends are atomic in CPython, maxlen gives ring
                      semantics for free); the only lock guards ring
@@ -26,12 +27,25 @@ Design constraints, in order:
 Clocks are `time.monotonic` seconds (caller-overridable for tests),
 exported as microseconds — the unit Chrome trace events use.
 
+Nesting: each thread keeps a stack of its open spans, so every event
+records the span open around it on the same thread (`parent`) and each
+span its own process-unique `id`; a span's self time is its duration
+less its children's.  While enabled, `span()` also opens a
+`jax.profiler.TraceAnnotation` of the same name (`step_span()` a
+`StepTraceAnnotation`), so a profile captured with `jax.profiler` shows
+the program's phases on the host plane, on the device trace's clock.
+TraceMe events must nest per thread: lifecycles that interleave on one
+thread (a gateway request, a queued driver job) are recorded
+ring-only through `complete()`.  JAX is imported on the first enabled
+span, never by importing this module.
+
 One process-wide tracer (`get_tracer()`) serves every component:
 request ids must correlate across gateway, router, and N driver
 threads, which means one id namespace and one export surface.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 import threading
@@ -41,8 +55,19 @@ from typing import Any, Dict, List, Optional, Tuple
 
 DEFAULT_CAPACITY = 65536        # events per thread ring
 
-# event tuples: (ph, t_s, dur_s, name, cat, args_or_None)
+# event tuples: (ph, t_s, dur_s, name, cat, args_or_None, id, parent)
 #   ph "X" = complete span (dur_s meaningful), "i" = instant
+#   id: span id (None for instants); parent: id of the span open on the
+#   recording thread when the event began (None at top level and for
+#   complete(), whose interval the caller measured)
+
+
+@functools.cache
+def _annotations():
+    """The profiler's (TraceAnnotation, StepTraceAnnotation), imported on
+    the first enabled span."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    return TraceAnnotation, StepTraceAnnotation
 
 
 class _Ring:
@@ -50,41 +75,79 @@ class _Ring:
     exporters snapshot via list(), which is safe against concurrent
     appends in CPython (worst case: an event lands after the copy)."""
 
-    __slots__ = ("events", "tid", "thread_name", "pushes")
+    __slots__ = ("events", "tid", "thread_name", "pushes", "stack")
 
     def __init__(self, capacity: int, tid: int, thread_name: str):
         self.events: deque = deque(maxlen=capacity)
         self.tid = tid
         self.thread_name = thread_name
         self.pushes = 0         # total ever; minus len() = dropped
+        self.stack: List[int] = []      # ids of this thread's open spans
 
     @property
     def dropped(self) -> int:
         return self.pushes - len(self.events)
 
+    def push(self, event: Tuple) -> None:
+        self.events.append(event)
+        self.pushes += 1
+
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit.
-    Exceptions propagate; the span still closes (the trace should show
-    the step that blew up, not end just before it)."""
+    """Context manager recording one complete ("X") event on exit, with
+    a profiler annotation open around it.  Exceptions propagate; the
+    span still closes (the trace should show the step that blew up, not
+    end just before it).  `dur_s` holds the duration after exit."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_step", "_t0",
+                 "_id", "_parent", "_ring", "_note", "dur_s")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict]):
+                 args: Optional[Dict], step: Optional[int] = None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._step = step
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._clock()
+        t = self._tracer
+        ring = self._ring = t._ring()
+        self._parent = ring.stack[-1] if ring.stack else None
+        self._id = next(t._span_ids)
+        ring.stack.append(self._id)
+        plain, step = _annotations()
+        self._note = (plain(self._name) if self._step is None
+                      else step(self._name, step_num=self._step))
+        self._note.__enter__()
+        self._t0 = t._clock()
         return self
 
     def __exit__(self, *exc) -> None:
         t = self._tracer
-        t._push(("X", self._t0, t._clock() - self._t0, self._name,
-                 self._cat, self._args))
+        self.dur_s = t._clock() - self._t0
+        self._note.__exit__(*exc)
+        ring = self._ring
+        ring.stack.pop()
+        ring.push(("X", self._t0, self.dur_s, self._name, self._cat,
+                   self._args, self._id, self._parent))
+
+
+class _Timer:
+    """What `timed()` returns with tracing off: the block's duration on
+    the tracer's clock, and no event."""
+
+    __slots__ = ("_clock", "_t0", "dur_s")
+
+    def __init__(self, clock):
+        self._clock = clock
+
+    def __enter__(self) -> "_Timer":
+        self._t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur_s = self._clock() - self._t0
 
 
 class _NullSpan:
@@ -113,6 +176,7 @@ class Tracer:
         self._rings: List[_Ring] = []
         self._reg_lock = threading.Lock()
         self._rid_counter = itertools.count()
+        self._span_ids = itertools.count(1)
         self.pid = os.getpid()
 
     # -- lifecycle ------------------------------------------------------
@@ -129,6 +193,10 @@ class Tracer:
         for ring in list(self._rings):
             ring.events.clear()
             ring.pushes = 0
+
+    def now(self) -> float:
+        """The tracer's clock, for intervals handed to `complete()`."""
+        return self._clock()
 
     def next_request_id(self) -> int:
         """Process-unique request id: the one value that ties a
@@ -148,9 +216,7 @@ class Tracer:
         return ring
 
     def _push(self, event: Tuple) -> None:
-        ring = self._ring()
-        ring.events.append(event)
-        ring.pushes += 1
+        self._ring().push(event)
 
     def instant(self, name: str, cat: str = "engine",
                 **args: Any) -> None:
@@ -158,7 +224,9 @@ class Tracer:
         with `if tracer.enabled:` so the kwargs dict is never built."""
         if not self.enabled:
             return
-        self._push(("i", self._clock(), 0.0, name, cat, args or None))
+        ring = self._ring()
+        ring.push(("i", self._clock(), 0.0, name, cat, args or None,
+                   None, ring.stack[-1] if ring.stack else None))
 
     def span(self, name: str, cat: str = "engine", **args: Any):
         """`with tracer.span("prefill_chunk", lanes=3): ...`"""
@@ -166,14 +234,32 @@ class Tracer:
             return NULL_SPAN
         return _Span(self, name, cat, args or None)
 
+    def step_span(self, name: str, step_num: int, cat: str = "engine",
+                  **args: Any):
+        """A span for one iteration of a loop: the profiler sees a
+        `StepTraceAnnotation` numbered `step_num`."""
+        if not self.enabled:
+            return NULL_SPAN
+        return _Span(self, name, cat, args or None, step=step_num)
+
+    def timed(self, name: str, cat: str = "engine", **args: Any):
+        """Like `span()`, but the block is timed with tracing off too:
+        `.dur_s` after exit.  A caller that needs the duration anyway
+        reads the very interval its span records."""
+        if not self.enabled:
+            return _Timer(self._clock)
+        return _Span(self, name, cat, args or None)
+
     def complete(self, name: str, t0: float, dur_s: float,
                  cat: str = "engine", **args: Any) -> None:
-        """Record a span whose interval was measured by the caller
-        (the engine already times its jitted dispatches; re-measuring
-        around them would double the clock reads)."""
+        """Record a span whose interval was measured by the caller:
+        a lifecycle that does not nest with the thread's other spans
+        (a gateway request, a driver job's wait in the inbox).  It has
+        no parent and reaches the ring only, never the profiler."""
         if not self.enabled:
             return
-        self._push(("X", t0, dur_s, name, cat, args or None))
+        self._push(("X", t0, dur_s, name, cat, args or None,
+                    next(self._span_ids), None))
 
     # -- export side ----------------------------------------------------
     def rings(self) -> List[_Ring]:
@@ -185,12 +271,13 @@ class Tracer:
         obs/export.py turns these into Chrome trace events."""
         out: List[Dict[str, Any]] = []
         for ring in self.rings():
-            for ph, t_s, dur_s, name, cat, args in list(ring.events):
+            for (ph, t_s, dur_s, name, cat, args, sid,
+                 parent) in list(ring.events):
                 out.append({"ph": ph, "t_s": t_s, "dur_s": dur_s,
                             "name": name, "cat": cat,
                             "tid": ring.tid,
                             "thread_name": ring.thread_name,
-                            "args": args})
+                            "args": args, "id": sid, "parent": parent})
         out.sort(key=lambda e: e["t_s"])
         return out
 

@@ -31,8 +31,9 @@ one lane, and recurrent prefill is one masked-scan device call per
 chunk, not one call per token.
 
 Every step runs at most two jitted graphs with shape-stable arguments:
-one chunked BATCH PREFILL call (b = max_batch, s = prefill_chunk) and
-one decode call — `DecoderLM.serve_step` (b = max_batch, s = 1), or,
+one chunked BATCH PREFILL call (b = max_batch, s = prefill_chunk; the
+program `jit_serve_prefill`) and one decode call — `DecoderLM.serve_step`
+(b = max_batch, s = 1; the program `jit_serve_decode`), or,
 when the engine is built with a `repro.spec.SpecConfig`, one
 `paged_verify_step` (b = max_batch, s = k + 1) that verifies a drafted
 window and emits a variable number of tokens per lane (speculative
@@ -49,6 +50,7 @@ shim; every token-input family now routes to the paged runtime.
 """
 from __future__ import annotations
 
+import functools
 import time
 import warnings
 from dataclasses import dataclass, field, replace as dc_replace
@@ -112,6 +114,20 @@ def _config_from_legacy(max_batch, max_seq, page_size, n_pages,
         eos_id=None if eos_id is _UNSET else eos_id,
         seed=0 if seed is _UNSET else seed,
         prefix_cache=None if prefix_cache is _UNSET else prefix_cache)
+
+
+def serve_prefill(model, params, state, inputs, tables, lengths, n_new):
+    """`model.serve_step` for a `(b, s > 1)` chunked prefill.  Jitted
+    with the model static, it is the program `jit_serve_prefill`, which
+    a device trace tells apart from the decode program; being one
+    module-level function, every engine on a model shares its compile."""
+    return model.serve_step(params, state, inputs, tables, lengths, n_new)
+
+
+def serve_decode(model, params, state, inputs, tables, lengths, n_new):
+    """`model.serve_step` for a `(b, 1)` decode step: the program
+    `jit_serve_decode`."""
+    return model.serve_step(params, state, inputs, tables, lengths, n_new)
 
 
 class PagedServeEngine:
@@ -249,11 +265,14 @@ class PagedServeEngine:
         self.energy = EnergyMeter(
             model.cfg, w_bits=config.weight_bits(),
             a_bits=8 if config.quantized() else 16, tp=config.tp)
-        self._last_t0 = 0.0
         self._cow_seen = 0          # deltas -> cow_copy / prefix_evict
         self._evict_seen = 0        # trace instants per step
         self.lanes: List[Optional[ServeRequest]] = [None] * max_batch
-        self._step_fn = self._jit_step(model.serve_step)
+        # the serve step as two named programs, so a device trace tells
+        # the (b, s > 1) chunked prefill from the (b, 1) decode
+        self._prefill_fn = self._jit_step(serve_prefill)
+        self._decode_fn = self._jit_step(serve_decode)
+        self._step_fn = self._serve_step
         self._key = jax.random.PRNGKey(seed)
         self._next_eid = 0
         if spec is not None:            # SpecConfig -> speculative decode
@@ -265,8 +284,8 @@ class PagedServeEngine:
                 # the verify window runs the very same sharded layout
                 # as decode (the draft model stays single-device: it is
                 # deliberately small enough not to need the mesh)
-                self.spec.verify_fn = self._jit_step(
-                    model.paged_verify_step)
+                self.spec.verify_fn = functools.partial(
+                    self._jit_step(type(model).paged_verify_step), model)
         else:
             self.spec = None
 
@@ -299,7 +318,9 @@ class PagedServeEngine:
             self._state_shardings.update(arena_sh)
 
     def _jit_step(self, fn):
-        """Jit a (params, state, inputs, tables, lengths, n_new) step.
+        """Jit a (model, params, state, inputs, tables, lengths, n_new)
+        step, the model static, as the program `jit_<fn name>`, the name
+        a device trace shows.
 
         tp == 1: plain jit, byte-for-byte the pre-TP path.  tp > 1: the
         step traces inside `use_mesh_rules`, so the model's
@@ -309,17 +330,27 @@ class PagedServeEngine:
         pool/arena shardings — donation then reuses the input buffers
         shard-for-shard and the layout can never drift step to step."""
         if self.mesh is None:
-            return jax.jit(fn, donate_argnums=(1,))
+            return jax.jit(fn, static_argnums=(0,), donate_argnums=(2,))
         from repro.dist import SERVE_RULES, use_mesh_rules
         mesh = self.mesh
 
-        def traced(params, state, inputs, tables, lengths, n_new):
+        def traced(model, params, state, inputs, tables, lengths, n_new):
             with use_mesh_rules(mesh, SERVE_RULES):
-                return fn(params, state, inputs, tables, lengths, n_new)
+                return fn(model, params, state, inputs, tables, lengths,
+                          n_new)
 
-        return jax.jit(traced, donate_argnums=(1,),
+        traced.__name__ = traced.__qualname__ = fn.__name__
+        return jax.jit(traced, static_argnums=(0,), donate_argnums=(2,),
                        out_shardings=(self._replicated,
                                       dict(self._state_shardings)))
+
+    def _serve_step(self, params, state, inputs, tables, lengths, n_new):
+        """`DecoderLM.serve_step` through its program for the shape:
+        `jit_serve_decode` for one token per lane, else
+        `jit_serve_prefill`."""
+        fn = (self._decode_fn if inputs["tokens"].shape[1] == 1
+              else self._prefill_fn)
+        return fn(self.model, params, state, inputs, tables, lengths, n_new)
 
     # ------------------------------------------------------------------
     def _event(self, kind: str, **fields: Any) -> None:
@@ -391,35 +422,49 @@ class PagedServeEngine:
         return requests
 
     # ------------------------------------------------------------------
-    def _dispatch(self, fn, tokens: np.ndarray, tables: np.ndarray,
-                  lengths: np.ndarray, n_new: np.ndarray):
+    def _dispatch(self, fn, name: str, args: Dict, tokens: np.ndarray,
+                  tables: np.ndarray, lengths: np.ndarray,
+                  n_new: np.ndarray):
         """Run one jitted step: flatten paged pools + arena slots into
         the unified cache dict (their key sets are disjoint by
         construction), split the returned state back.  Returns
         (logits, graph seconds) — the clock stops once the device has
-        produced the logits, not when the step was enqueued."""
+        produced the logits, not when the step was enqueued.  Those
+        seconds are the span `name` (with `args`), split into `enqueue`
+        (host-to-device copies and the call) and `device_wait`."""
         state = dict(self.cache.pools)
         if self.arena is not None:
             state.update(self.arena.state)
-        t0 = time.monotonic()
-        logits, state = fn(
-            self.params, state, {"tokens": jnp.asarray(tokens)},
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(n_new))
-        jax.block_until_ready(logits)
-        dt = time.monotonic() - t0
+        tr = self.tracer
+        with tr.timed(name, **args) as call:
+            with tr.span("enqueue"):
+                logits, state = fn(
+                    self.params, state, {"tokens": jnp.asarray(tokens)},
+                    jnp.asarray(tables), jnp.asarray(lengths),
+                    jnp.asarray(n_new))
+            with tr.span("device_wait"):
+                jax.block_until_ready(logits)
+        dt = call.dur_s
         if self.mesh is not None:
             # one gathered host copy: every downstream consumer
             # (sampling, logprobs, verify walks) runs on identical
             # bytes regardless of tp — the byte-identity invariant
             # lives here
             logits = jax.device_get(logits)
-        self._last_t0 = t0      # span start for tracer.complete()
         if self.arena is not None:
             self.arena.state = {k: state[k] for k in self.arena.keys}
             self.cache.pools = {k: state[k] for k in self._paged_keys}
         else:
             self.cache.pools = state
         return logits, dt
+
+    def _lane_args(self, lanes: List[int], **more: Any) -> Dict:
+        """Span args of a dispatch advancing `lanes`: the request ids
+        and lane count, built only while tracing."""
+        if not self.tracer.enabled:
+            return {}
+        return dict(rids=[self.lanes[i].trace_id for i in lanes],
+                    lanes=len(lanes), **more)
 
     def _tables(self) -> np.ndarray:
         tab = np.zeros((self.max_batch, self.cache.max_pages), np.int32)
@@ -551,6 +596,44 @@ class PagedServeEngine:
 
     # ------------------------------------------------------------------
     def step(self) -> None:
+        with self.tracer.span("engine_step"):
+            self._step()
+
+    def _step(self) -> None:
+        with self.tracer.span("admit"):
+            self._admit()
+        prefill_s = self._prefill_phase()
+        if self.spec is not None:
+            decode_s, decode_lanes = self._decode_phase_spec()
+        else:
+            decode_s, decode_lanes = self._decode_phase()
+        # page-sharing machinery reports deltas, not per-call hooks:
+        # surface them as per-step instants when tracing
+        if self.tracer.enabled:
+            if self.cache.cow_copies > self._cow_seen:
+                self.tracer.instant(
+                    "cow_copy", cat="engine",
+                    n=self.cache.cow_copies - self._cow_seen)
+            evicted = (self.prefix.pages_evicted
+                       if self.prefix is not None else 0)
+            if evicted > self._evict_seen:
+                self.tracer.instant("prefix_evict", cat="engine",
+                                    n=evicted - self._evict_seen)
+        self._cow_seen = self.cache.cow_copies
+        self._evict_seen = (self.prefix.pages_evicted
+                            if self.prefix is not None else 0)
+        # arena slots are engine lanes 1:1, so slot fill is running
+        # lanes over max_batch — sampled only when an arena exists
+        state_occ = (self.n_running / self.max_batch
+                     if self.arena is not None else None)
+        self.telemetry.step(self.cache.occupancy(), self.n_running,
+                            decode_s=decode_s, prefill_s=prefill_s,
+                            decode_lanes=decode_lanes,
+                            state_occupancy=state_occ,
+                            family=self.model.cfg.family)
+
+    def _admit(self) -> None:
+        """Admit queued requests into free lanes and set the lanes up."""
         now = self._clock()
 
         def _reject(r: ServeRequest) -> None:
@@ -586,36 +669,6 @@ class PagedServeEngine:
             elif self.prefix is not None:
                 self.telemetry.prefix(req.prefix_cached)
 
-        prefill_s = self._prefill_phase()
-        if self.spec is not None:
-            decode_s, decode_lanes = self._decode_phase_spec()
-        else:
-            decode_s, decode_lanes = self._decode_phase()
-        # page-sharing machinery reports deltas, not per-call hooks:
-        # surface them as per-step instants when tracing
-        if self.tracer.enabled:
-            if self.cache.cow_copies > self._cow_seen:
-                self.tracer.instant(
-                    "cow_copy", cat="engine",
-                    n=self.cache.cow_copies - self._cow_seen)
-            evicted = (self.prefix.pages_evicted
-                       if self.prefix is not None else 0)
-            if evicted > self._evict_seen:
-                self.tracer.instant("prefix_evict", cat="engine",
-                                    n=evicted - self._evict_seen)
-        self._cow_seen = self.cache.cow_copies
-        self._evict_seen = (self.prefix.pages_evicted
-                            if self.prefix is not None else 0)
-        # arena slots are engine lanes 1:1, so slot fill is running
-        # lanes over max_batch — sampled only when an arena exists
-        state_occ = (self.n_running / self.max_batch
-                     if self.arena is not None else None)
-        self.telemetry.step(self.cache.occupancy(), self.n_running,
-                            decode_s=decode_s, prefill_s=prefill_s,
-                            decode_lanes=decode_lanes,
-                            state_occupancy=state_occ,
-                            family=self.model.cfg.family)
-
     def _prefill_phase(self) -> float:
         """One chunked BATCH prefill call for every lane with prompt
         tokens left; lanes finishing their prompt sample their first
@@ -624,60 +677,64 @@ class PagedServeEngine:
                if r is not None and r.prefill_remaining > 0]
         if not pre:
             return 0.0
-        s = self.scheduler.prefill_chunk
-        tokens = np.zeros((self.max_batch, s), np.int32)
-        n_new = np.zeros(self.max_batch, np.int32)
-        finishing = False
-        for i in list(pre):
-            req = self.lanes[i]
-            q = self.scheduler.prefill_quota(req)
-            # prompt pages were allocated at admission, but a forked /
-            # resubmitted lane may start mid-page on a shared page:
-            # copy-on-write it before the chunk lands
-            if not self.cache.prepare_write(req.eid, q):
-                self._preempt(i)
-                pre.remove(i)
-                continue
-            tokens[i, :q] = req.prompt[req.prefill_done:req.prefill_done + q]
-            n_new[i] = q
-            finishing |= q == req.prefill_remaining
-        if not pre:
-            return 0.0
-        logits, dt = self._dispatch(self._step_fn, tokens, self._tables(),
-                                    self._lengths(), n_new)
+        tr = self.tracer
+        with tr.span("build_inputs"):
+            s = self.scheduler.prefill_chunk
+            tokens = np.zeros((self.max_batch, s), np.int32)
+            n_new = np.zeros(self.max_batch, np.int32)
+            finishing = False
+            for i in list(pre):
+                req = self.lanes[i]
+                q = self.scheduler.prefill_quota(req)
+                # prompt pages were allocated at admission, but a forked
+                # / resubmitted lane may start mid-page on a shared
+                # page: copy-on-write it before the chunk lands
+                if not self.cache.prepare_write(req.eid, q):
+                    self._preempt(i)
+                    pre.remove(i)
+                    continue
+                tokens[i, :q] = req.prompt[req.prefill_done:
+                                           req.prefill_done + q]
+                n_new[i] = q
+                finishing |= q == req.prefill_remaining
+            if not pre:
+                return 0.0
+            tables, lengths = self._tables(), self._lengths()
+        chunk_tokens = int(n_new.sum())
+        logits, dt = self._dispatch(
+            self._step_fn, "prefill_chunk",
+            self._lane_args(pre, tokens=chunk_tokens),
+            tokens, tables, lengths, n_new)
 
         if finishing:       # only sample when some lane ends its prompt
-            last = jnp.take_along_axis(
-                logits, jnp.asarray(np.maximum(n_new - 1, 0)
-                                    )[:, None, None], axis=1)[:, 0, :]
-            nxt = self._sample_rows(last)
-        now = self._clock()
-        chunk_rids = [self.lanes[i].trace_id for i in pre]
-        chunk_tokens = 0
-        for i in pre:
-            req = self.lanes[i]
-            q = int(n_new[i])
-            req.prefill_done += q
-            chunk_tokens += q
-            self.cache.seqs[req.eid].length += q
-            self.telemetry.prefill_tokens += q
-            if req.prefill_remaining == 0:
-                if self.prefix is not None:
-                    # prompt fully materialized: commit its full pages
-                    # so later requests with the same prefix skip them
-                    self.prefix.insert(np.asarray(req.prompt, np.int32),
-                                       self.cache.seqs[req.eid].pages)
-                self._emit(req, int(nxt[i]), now, decode=False,
-                           row=np.asarray(last[i])
-                           if req.logprobs else None)
-                self._maybe_finish(i, now)
-        self.energy.charge_prefill(chunk_tokens)
-        self.recorder.record("prefill_chunk", lanes=len(pre),
-                             tokens=chunk_tokens, dur_s=dt)
-        if self.tracer.enabled:
-            self.tracer.complete(
-                "prefill_chunk", self._last_t0, dt, cat="engine",
-                rids=chunk_rids, lanes=len(pre), tokens=chunk_tokens)
+            with tr.span("sample"):
+                last = jnp.take_along_axis(
+                    logits, jnp.asarray(np.maximum(n_new - 1, 0)
+                                        )[:, None, None], axis=1)[:, 0, :]
+                nxt = self._sample_rows(last)
+        with tr.span("emit"):
+            now = self._clock()
+            for i in pre:
+                req = self.lanes[i]
+                q = int(n_new[i])
+                req.prefill_done += q
+                self.cache.seqs[req.eid].length += q
+                self.telemetry.prefill_tokens += q
+                if req.prefill_remaining == 0:
+                    if self.prefix is not None:
+                        # prompt fully materialized: commit its full
+                        # pages so later requests with the same prefix
+                        # skip them
+                        self.prefix.insert(
+                            np.asarray(req.prompt, np.int32),
+                            self.cache.seqs[req.eid].pages)
+                    self._emit(req, int(nxt[i]), now, decode=False,
+                               row=np.asarray(last[i])
+                               if req.logprobs else None)
+                    self._maybe_finish(i, now)
+            self.energy.charge_prefill(chunk_tokens)
+            self.recorder.record("prefill_chunk", lanes=len(pre),
+                                 tokens=chunk_tokens, dur_s=dt)
         return dt
 
     def _decode_ready(self) -> List[int]:
@@ -692,45 +749,48 @@ class PagedServeEngine:
     def _decode_phase(self) -> tuple:
         """One token for every decode-ready lane.  Returns (graph
         seconds, lanes advanced)."""
-        ready = []
-        for i in self._decode_ready():
-            req = self.lanes[i]
-            # the token we feed is the last emitted one; this decode call
-            # itself writes its KV row at position seqs[rid].length
-            # (prepare_write also copy-on-writes a shared tail page)
-            if not self.cache.prepare_write(req.eid, 1):
-                self._preempt(i)
-                continue
-            ready.append(i)
-        if not ready:
+        dec = self._decode_ready()
+        if not dec:
             return 0.0, 0
-
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        n_new = np.zeros(self.max_batch, np.int32)
-        for i in ready:
-            req = self.lanes[i]
-            tokens[i, 0] = req.out_tokens[-1]
-            n_new[i] = 1
-        lens = self._lengths()
-        logits, dt = self._dispatch(self._step_fn, tokens, self._tables(),
+        tr = self.tracer
+        with tr.span("build_inputs"):
+            ready = []
+            for i in dec:
+                req = self.lanes[i]
+                # the token we feed is the last emitted one; this decode
+                # call itself writes its KV row at position
+                # seqs[rid].length (prepare_write also copy-on-writes a
+                # shared tail page)
+                if not self.cache.prepare_write(req.eid, 1):
+                    self._preempt(i)
+                    continue
+                ready.append(i)
+            if not ready:
+                return 0.0, 0
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            n_new = np.zeros(self.max_batch, np.int32)
+            for i in ready:
+                req = self.lanes[i]
+                tokens[i, 0] = req.out_tokens[-1]
+                n_new[i] = 1
+            tables, lens = self._tables(), self._lengths()
+        logits, dt = self._dispatch(self._step_fn, "decode_step",
+                                    self._lane_args(ready), tokens, tables,
                                     lens, n_new)
 
-        nxt = self._sample_rows(logits[:, 0, :])
-        now = self._clock()
-        rids = [self.lanes[i].trace_id for i in ready]
-        self.energy.charge_decode(len(ready), float(lens[ready].mean()))
-        self.recorder.record("decode_step", lanes=len(ready), dur_s=dt)
-        if self.tracer.enabled:
-            self.tracer.complete("decode_step", self._last_t0, dt,
-                                 cat="engine", rids=rids,
-                                 lanes=len(ready))
-        for i in ready:
-            req = self.lanes[i]
-            self.cache.seqs[req.eid].length += 1
-            self._emit(req, int(nxt[i]), now,
-                       row=np.asarray(logits[i, 0, :])
-                       if req.logprobs else None)
-            self._maybe_finish(i, now)
+        with tr.span("sample"):
+            nxt = self._sample_rows(logits[:, 0, :])
+        with tr.span("emit"):
+            now = self._clock()
+            self.energy.charge_decode(len(ready), float(lens[ready].mean()))
+            self.recorder.record("decode_step", lanes=len(ready), dur_s=dt)
+            for i in ready:
+                req = self.lanes[i]
+                self.cache.seqs[req.eid].length += 1
+                self._emit(req, int(nxt[i]), now,
+                           row=np.asarray(logits[i, 0, :])
+                           if req.logprobs else None)
+                self._maybe_finish(i, now)
         return dt, len(ready)
 
     def _decode_phase_spec(self) -> tuple:
@@ -751,6 +811,7 @@ class PagedServeEngine:
         if not dec:
             return 0.0, 0
 
+        tr = self.tracer
         histories: List[Optional[np.ndarray]] = [None] * self.max_batch
         smp: List[Optional[SamplingParams]] = [None] * self.max_batch
         for i in dec:
@@ -766,38 +827,41 @@ class PagedServeEngine:
         # drafting is part of the decode budget speculation spends —
         # timing it keeps tokens_per_s_decode (and spec_bench's speedup
         # column) honest about what a model drafter costs
-        t0 = time.monotonic()
-        prop = spec.drafter.propose(histories, k_draft, smp)
-        draft_s = time.monotonic() - t0
+        with tr.timed("spec_draft", **self._lane_args(dec)) as draft:
+            prop = spec.drafter.propose(histories, k_draft, smp)
+        draft_s = draft.dur_s
 
-        tokens = np.zeros((self.max_batch, k + 1), np.int32)
-        n_new = np.zeros(self.max_batch, np.int32)
-        ready: List[tuple] = []                 # (lane, n_draft)
-        for i in dec:
-            req = self.lanes[i]
-            nd = int(prop.n[i]) if histories[i] is not None else 0
-            # the window writes 1 + nd KV rows and may emit 1 + nd
-            # tokens; cap at the sequence budget AND the request's
-            # remaining token budget (no point verifying tokens
-            # emitted[:budget] would discard), then shrink until the
-            # pool can hold it (a shrunk window beats a preemption)
-            nd = max(0, min(nd,
-                            self.max_seq
-                            - self.cache.seqs[req.eid].length - 1,
-                            req.max_new_tokens - len(req.out_tokens) - 1))
-            while nd > 0 and not self.cache.prepare_write(req.eid, 1 + nd):
-                nd -= 1
-            if nd == 0 and not self.cache.prepare_write(req.eid, 1):
-                self._preempt(i)
-                continue
-            tokens[i, 0] = req.out_tokens[-1]
-            tokens[i, 1:1 + nd] = prop.tokens[i, :nd]
-            n_new[i] = 1 + nd
-            ready.append((i, nd))
-        if not ready:
-            return 0.0, 0
-        lengths = self._lengths()
-        tables = self._tables()
+        with tr.span("build_inputs"):
+            tokens = np.zeros((self.max_batch, k + 1), np.int32)
+            n_new = np.zeros(self.max_batch, np.int32)
+            ready: List[tuple] = []                 # (lane, n_draft)
+            for i in dec:
+                req = self.lanes[i]
+                nd = int(prop.n[i]) if histories[i] is not None else 0
+                # the window writes 1 + nd KV rows and may emit 1 + nd
+                # tokens; cap at the sequence budget AND the request's
+                # remaining token budget (no point verifying tokens
+                # emitted[:budget] would discard), then shrink until the
+                # pool can hold it (a shrunk window beats a preemption)
+                nd = max(0, min(nd,
+                                self.max_seq
+                                - self.cache.seqs[req.eid].length - 1,
+                                req.max_new_tokens - len(req.out_tokens)
+                                - 1))
+                while nd > 0 and not self.cache.prepare_write(req.eid,
+                                                              1 + nd):
+                    nd -= 1
+                if nd == 0 and not self.cache.prepare_write(req.eid, 1):
+                    self._preempt(i)
+                    continue
+                tokens[i, 0] = req.out_tokens[-1]
+                tokens[i, 1:1 + nd] = prop.tokens[i, :nd]
+                n_new[i] = 1 + nd
+                ready.append((i, nd))
+            if not ready:
+                return 0.0, 0
+            lengths = self._lengths()
+            tables = self._tables()
 
         # nothing drafted anywhere this step: the (b, k+1) verify graph
         # would burn (k+1)x decode compute on an effectively plain step,
@@ -805,53 +869,48 @@ class PagedServeEngine:
         plain = all(nd == 0 for _, nd in ready)
         step_fn = self._step_fn if plain else spec.verify_fn
         step_tokens = tokens[:, :1] if plain else tokens
-
-        logits, dt = self._dispatch(step_fn, step_tokens, tables, lengths,
-                                    n_new)
-        verify_s = dt
+        lanes_idx = [i for i, _ in ready]
+        logits, dt = self._dispatch(
+            step_fn, "spec_verify",
+            self._lane_args(lanes_idx, drafted=sum(nd for _, nd in ready)),
+            step_tokens, tables, lengths, n_new)
         dt += draft_s
 
-        logits_np = np.asarray(logits)
-        now = self._clock()
-        drafted = accepted = n_emitted = 0
-        lanes_idx = [i for i, _ in ready]
-        rids = [self.lanes[i].trace_id for i in lanes_idx]
-        for i, nd in ready:
-            req = self.lanes[i]
-            q_rows = prop.probs[i, :nd] if prop.probs is not None else None
-            n_acc, emitted = spec.accept(
-                logits_np[i, :nd + 1], tokens[i, 1:1 + nd], q_rows,
-                req.sampling)
-            drafted += nd
-            accepted += n_acc
-            seq = self.cache.seqs[req.eid]
-            seq.length += n_acc + 1             # keep input + accepted rows
-            self.cache.trim(req.eid, seq.length)  # free rejected pages
-            if self.eos_id is not None and self.eos_id in emitted:
-                emitted = emitted[:emitted.index(self.eos_id) + 1]
-            budget = req.max_new_tokens - len(req.out_tokens)
-            # emitted[j] was accepted/sampled from verify-logits row j,
-            # so that row is its (target-model) logprob source
-            for j, tok in enumerate(emitted[:budget]):
-                self._emit(req, tok, now,
-                           row=logits_np[i, j] if req.logprobs else None)
-                n_emitted += 1
-            self._maybe_finish(i, now)
-        self.telemetry.spec(drafted, accepted)
-        spec.observe(drafted, accepted)
-        self.energy.charge_decode(
-            n_emitted, float(lengths[lanes_idx].mean()))
-        self.recorder.record("spec_verify", lanes=len(ready),
-                             drafted=drafted, accepted=accepted,
-                             dur_s=dt)
-        if self.tracer.enabled:
-            if draft_s > 0.0:
-                self.tracer.complete("spec_draft", t0, draft_s,
-                                     cat="engine", rids=rids)
-            self.tracer.complete("spec_verify", self._last_t0, verify_s,
-                                 cat="engine", rids=rids,
-                                 lanes=len(ready), drafted=drafted,
-                                 accepted=accepted)
+        with tr.span("sample"):
+            logits_np = np.asarray(logits)
+        with tr.span("emit"):
+            now = self._clock()
+            drafted = accepted = n_emitted = 0
+            for i, nd in ready:
+                req = self.lanes[i]
+                q_rows = (prop.probs[i, :nd] if prop.probs is not None
+                          else None)
+                n_acc, emitted = spec.accept(
+                    logits_np[i, :nd + 1], tokens[i, 1:1 + nd], q_rows,
+                    req.sampling)
+                drafted += nd
+                accepted += n_acc
+                seq = self.cache.seqs[req.eid]
+                seq.length += n_acc + 1         # keep input + accepted rows
+                self.cache.trim(req.eid, seq.length)  # free rejected pages
+                if self.eos_id is not None and self.eos_id in emitted:
+                    emitted = emitted[:emitted.index(self.eos_id) + 1]
+                budget = req.max_new_tokens - len(req.out_tokens)
+                # emitted[j] was accepted/sampled from verify-logits row
+                # j, so that row is its (target-model) logprob source
+                for j, tok in enumerate(emitted[:budget]):
+                    self._emit(req, tok, now,
+                               row=logits_np[i, j] if req.logprobs
+                               else None)
+                    n_emitted += 1
+                self._maybe_finish(i, now)
+            self.telemetry.spec(drafted, accepted)
+            spec.observe(drafted, accepted)
+            self.energy.charge_decode(
+                n_emitted, float(lengths[lanes_idx].mean()))
+            self.recorder.record("spec_verify", lanes=len(ready),
+                                 drafted=drafted, accepted=accepted,
+                                 dur_s=dt)
         return dt, len(ready)
 
     # ------------------------------------------------------------------

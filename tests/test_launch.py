@@ -15,17 +15,41 @@ from repro.serve import ServeConfig, ServeRequest
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
-def test_chip_smoke_refuses_cpu(monkeypatch, capsys):
+def _chip_smoke():
     spec = importlib.util.spec_from_file_location(
         "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
     smoke = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(smoke)
+    return smoke
+
+
+def test_chip_smoke_refuses_cpu(monkeypatch, capsys):
+    smoke = _chip_smoke()
     monkeypatch.setattr("sys.argv", ["chip_smoke.py"])
     assert jax.devices()[0].platform == "cpu"
     assert smoke.main() != 0
     out = capsys.readouterr()
     assert '"ok": true' not in out.out + out.err
     assert "needs a TPU" in out.err
+
+
+@pytest.mark.parametrize("precision", ["fp", "int4"])
+def test_chip_smoke_reads_the_engine_programs(precision):
+    """The smoke's handles on an engine's programs: the compiled decode
+    program it counts kernels in, and the prefill it compares logits of
+    across tp.  A renamed engine handle fails here, not on the chip."""
+    smoke = _chip_smoke()
+    model, params = load_model("qwen2.5-3b", smoke=True)
+    cfg = ServeConfig(precision=precision, quant_group=16, max_batch=2,
+                      max_seq=32, page_size=8, prefill_chunk=8)
+    eng = build_engines(model, params, cfg)[0]
+    hlo = smoke.decode_hlo(eng)
+    assert hlo.startswith("HloModule jit_serve_decode")
+    assert smoke.custom_calls(hlo, "paged_flash_attention") == 0  # CPU
+    prompts = [np.arange(1 + i, 9 + i, dtype=np.int32) for i in range(2)]
+    logits = smoke.first_step_logits(eng, prompts)
+    assert logits.shape == (2, model.cfg.vocab)
+    assert np.isfinite(logits).all()
 
 
 def test_load_model_dtypes():

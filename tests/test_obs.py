@@ -134,6 +134,93 @@ def test_per_thread_rings_and_unique_request_ids():
     assert len(set(ids)) == 400         # process-unique correlation ids
 
 
+def test_spans_record_ids_parents_and_self_time():
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    tr = Tracer(clock=clock).enable()
+    with tr.step_span("loop", 7, cat="driver"):
+        with tr.span("step"):
+            with tr.span("a"):
+                pass
+            tr.instant("mark")
+            with tr.span("b"):
+                pass
+        tr.complete("inbox", t0=0.5, dur_s=0.25, cat="driver")
+    evs = tr.events()
+    by = {e["name"]: e for e in evs}
+    ids = [e["id"] for e in evs if e["ph"] == "X"]
+    assert len(set(ids)) == len(ids) and None not in ids
+    assert by["loop"]["parent"] is None
+    assert by["step"]["parent"] == by["loop"]["id"]
+    assert by["a"]["parent"] == by["b"]["parent"] == by["step"]["id"]
+    assert by["mark"]["parent"] == by["step"]["id"]
+    assert by["mark"]["id"] is None
+    # complete() records a measured interval: no parent, ring only
+    assert by["inbox"]["parent"] is None
+    # self time = duration less the children's
+    kids = {}
+    for e in evs:
+        if e["ph"] == "X" and e["parent"] is not None:
+            kids[e["parent"]] = kids.get(e["parent"], 0.0) + e["dur_s"]
+    self_s = {e["name"]: e["dur_s"] - kids.get(e["id"], 0.0)
+              for e in evs if e["ph"] == "X"}
+    assert by["a"]["dur_s"] == by["b"]["dur_s"] == 1.0
+    assert by["step"]["dur_s"] == 6.0 and self_s["step"] == 4.0
+    assert by["loop"]["dur_s"] == 8.0 and self_s["loop"] == 2.0
+    # the stack unwinds: a later span is top level again
+    with tr.span("after"):
+        pass
+    assert tr.events()[-1]["parent"] is None
+
+
+def test_spans_open_profiler_annotations_only_while_enabled(monkeypatch):
+    import repro.obs.trace as trace_mod
+    built = []
+
+    class Note:
+        def __init__(self, name, **kw):
+            built.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(trace_mod, "_annotations", lambda: (Note, Note))
+    tr = Tracer()
+    with tr.span("x"), tr.step_span("loop", 3):
+        pass
+    with tr.timed("dispatch") as call:
+        pass
+    assert call.dur_s >= 0.0             # timed with tracing off too
+    assert tr.step_span("loop", 3) is NULL_SPAN
+    assert built == [] and tr.events() == []
+    tr.enable()
+    with tr.step_span("loop", 3), tr.span("x"):
+        with tr.timed("dispatch") as call:
+            pass
+    assert built == [("loop", {"step_num": 3}), ("x", {}),
+                     ("dispatch", {})]
+    ev = next(e for e in tr.events() if e["name"] == "dispatch")
+    assert ev["dur_s"] == call.dur_s     # the span is the timed interval
+
+
+def test_obs_does_not_import_jax():
+    import subprocess
+    import sys
+    code = ("import sys; import repro.obs.trace as t; tr = t.Tracer(); "
+            "tr.span('x'); tr.instant('i'); "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, check=True)
+    assert out.stdout.strip() == "False"
+
+
 # ----------------------------------------------------------------------------
 # Chrome trace export
 # ----------------------------------------------------------------------------
@@ -478,6 +565,127 @@ def test_tracing_disabled_emits_no_events(model_params):
     assert eng.recorder.pushes > 0
 
 
+def test_driven_engine_emits_the_phase_tree(model_params, tracing):
+    """One loop iteration per step: driver_loop > (driver_job,
+    sweep_done, engine_step > (admit, build_inputs, dispatch >
+    (enqueue, device_wait), sample, emit), tap); the dispatch spans sum
+    to the engine's own step clocks, and a submit's wait in the driver's
+    inbox is a ring-only span carrying its request ids.  Idle iterations
+    record no loop span: the idle stretch before the work is one
+    ring-only idle_wait, and the quiet stretch after it adds nothing."""
+    import time
+    model, params = model_params
+    eng = _engine(model, params, prefill_chunk=8)
+    driver = EngineDriver(eng, tap=lambda engine: None).start()
+    time.sleep(0.1)                 # idle iterations before the work
+    reqs = [ServeRequest(prompt=np.arange(1, 12, dtype=np.int32),
+                         max_new_tokens=4, rid=i) for i in range(2)]
+    for i, r in enumerate(reqs):
+        r.trace_id = 100 + i
+    finished = threading.Event()
+    driver.submit(reqs, lambda r: finished.set()
+                  if all(q.done for q in reqs) else None).result(60)
+    assert finished.wait(60)
+    time.sleep(0.2)                 # idle iterations after the work
+    driver.stop()
+    assert not driver.alive
+    evs = [e for e in tracing.events() if e["ph"] == "X"]
+    by_id = {e["id"]: e for e in evs}
+    kids = {}
+    for e in evs:
+        kids.setdefault(e["parent"], []).append(e)
+
+    def names(e):
+        return [k["name"] for k in sorted(kids.get(e["id"], []),
+                                          key=lambda k: k["t_s"])]
+
+    steps = [e for e in evs if e["name"] == "engine_step"]
+    assert len(steps) == eng.telemetry.steps
+    loops = [e for e in evs if e["name"] == "driver_loop"]
+    assert len(loops) == len(steps)
+    for st in steps:
+        loop = by_id[st["parent"]]
+        assert loop["name"] == "driver_loop"
+        assert names(loop)[-1] == "tap"
+        ph = names(st)
+        assert ph[0] == "admit"
+        # each dispatch comes with its inputs before and sampling /
+        # emission after, in that order
+        for i, n in enumerate(ph):
+            if n in ("prefill_chunk", "decode_step"):
+                assert ph[i - 1] == "build_inputs"
+                assert ph[i + 1] in ("sample", "emit")
+    # jobs and sweeps run inside a stepping loop, or at top level in
+    # an idle iteration (the first submit, the last completions)
+    for name in ("driver_job", "sweep_done"):
+        found = [e for e in evs if e["name"] == name]
+        assert found and all(e["parent"] is None
+                             or by_id[e["parent"]]["name"] == "driver_loop"
+                             for e in found)
+    disp = [e for e in evs if e["name"] in ("prefill_chunk", "decode_step")]
+    assert {e["name"] for e in disp} == {"prefill_chunk", "decode_step"}
+    for d in disp:
+        assert names(d) == ["enqueue", "device_wait"]
+        wait = max(kids[d["id"]], key=lambda k: k["t_s"])
+        assert d["t_s"] <= wait["t_s"]
+        assert wait["t_s"] + wait["dur_s"] <= d["t_s"] + d["dur_s"]
+        assert set(d["args"]["rids"]) <= {100, 101}
+    tel = eng.telemetry
+    assert sum(e["dur_s"] for e in disp if e["name"] == "prefill_chunk") \
+        == pytest.approx(tel.prefill_s, rel=1e-12, abs=1e-12)
+    assert sum(e["dur_s"] for e in disp if e["name"] == "decode_step") \
+        == pytest.approx(tel.decode_s, rel=1e-12, abs=1e-12)
+    idle = [e for e in evs if e["name"] == "idle_wait"]
+    assert len(idle) == 1 and idle[0]["parent"] is None
+    assert idle[0]["t_s"] + idle[0]["dur_s"] <= min(e["t_s"] for e in loops)
+    inbox = [e for e in evs if e["name"] == "driver_inbox"]
+    assert any(e["args"]["rids"] == [100, 101] for e in inbox)
+    assert all(e["parent"] is None and e["cat"] == "driver"
+               for e in inbox)
+    # the Chrome export carries the nesting in args
+    doc = chrome_trace(tracing)
+    dec = next(e for e in doc["traceEvents"] if e["name"] == "decode_step")
+    assert by_id[dec["args"]["parent"]]["name"] == "engine_step"
+    assert dec["args"]["id"] in by_id
+
+
+def test_serve_programs_are_named_and_scope_every_projection(model_params):
+    """Prefill and decode lower as two programs of their own names, and
+    each projection's ops carry its scope; no scope matches a kernel's
+    name, which the benchmark's trace reduction looks for."""
+    from repro.serve import ServeConfig
+    model, params = model_params
+    eng = PagedServeEngine(model, params, ServeConfig(
+        precision="int4", max_batch=2, max_seq=32, page_size=8,
+        prefill_chunk=8))
+    scopes = ("q_proj", "k_proj", "v_proj", "o_proj", "gate_up",
+              "down_proj", "lm_head", "embed")
+    b = 2
+    for fn, s, name in ((eng._prefill_fn, 8, "jit_serve_prefill"),
+                        (eng._decode_fn, 1, "jit_serve_decode")):
+        text = fn.lower(
+            eng.model, eng.params, dict(eng.cache.pools),
+            {"tokens": jnp.zeros((b, s), jnp.int32)},
+            jnp.zeros((b, eng.cache.max_pages), jnp.int32),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32)
+        ).as_text(dialect="hlo", debug_info=True)
+        assert text.startswith(f"HloModule {name},")
+        for scope in scopes:
+            assert f"{scope}/" in text, (name, scope)
+            assert "paged_flash_attention" not in scope
+            assert "swiglu_qgemv" not in scope
+    # the engine's one step entry routes each shape to its program
+    seen = []
+    for attr in ("_prefill_fn", "_decode_fn"):
+        orig = getattr(eng, attr)
+        setattr(eng, attr, lambda *a, _o=orig, _n=attr: (
+            seen.append(_n), _o(*a))[1])
+    eng.run([ServeRequest(prompt=np.arange(1, 12, dtype=np.int32),
+                          max_new_tokens=3, rid=0)])
+    assert seen[:2] == ["_prefill_fn", "_prefill_fn"]
+    assert set(seen[2:]) == {"_decode_fn"}
+
+
 # ----------------------------------------------------------------------------
 # trace_view CLI
 # ----------------------------------------------------------------------------
@@ -516,3 +724,40 @@ def test_trace_view_rollup(tmp_path, capsys):
     assert tv.main([str(path), "--top", "2"]) == 0
     out = capsys.readouterr().out
     assert "decode_step" in out and "slowest requests" in out
+
+
+def test_trace_view_counts_nested_time_once(tmp_path, capsys):
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "trace_view", os.path.join(os.path.dirname(__file__), "..",
+                                   "tools", "trace_view.py"))
+    tv = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tv)
+
+    def x(name, ts, dur, sid, parent=None):
+        args = {"id": sid}
+        if parent is not None:
+            args["parent"] = parent
+        return {"ph": "X", "name": name, "cat": "engine", "ts": ts,
+                "dur": dur, "pid": 1, "tid": 2, "args": args}
+
+    events = [x("driver_loop", 0, 1000.0, 1),
+              x("engine_step", 10, 900.0, 2, 1),
+              x("decode_step", 20, 600.0, 3, 2),
+              x("enqueue", 20, 100.0, 4, 3),
+              x("device_wait", 120, 500.0, 5, 3),
+              x("sample", 620, 200.0, 6, 2)]
+    agg = tv.phase_breakdown(events)
+    assert agg["driver_loop"]["self_us"] == pytest.approx(100.0)
+    assert agg["engine_step"]["self_us"] == pytest.approx(100.0)
+    assert agg["decode_step"]["self_us"] == pytest.approx(0.0)
+    assert agg["decode_step"]["total_us"] == pytest.approx(600.0)
+    assert agg["device_wait"]["self_us"] == pytest.approx(500.0)
+    # self times add up to the outermost span: nothing counted twice
+    assert sum(a["self_us"] for a in agg.values()) == pytest.approx(1000.0)
+    path = tmp_path / "t.json"
+    path.write_text(json.dumps({"traceEvents": events}))
+    assert tv.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "self" in out and "device_wait" in out

@@ -8,6 +8,11 @@ you ask before opening a UI at all:
     (queue vs prefill vs decode), and
   * what does a decode step cost per phase across the whole capture.
 
+A span's self time is its duration less its children's (the spans
+whose `args.parent` is its `args.id`), so nested phases — a
+`decode_step` inside `engine_step` inside `driver_loop` — are not
+counted twice: the self times of a capture add up to its traced time.
+
 Usage:
     python tools/trace_view.py trace.json [--top 10]
     curl -s localhost:8151/debug/trace | python tools/trace_view.py -
@@ -41,14 +46,23 @@ def _ms(us: float) -> str:
 
 
 def phase_breakdown(events: List[Dict]) -> Dict[str, Dict[str, float]]:
-    """Aggregate complete spans by name: count, total ms, mean us."""
+    """Aggregate complete spans by name: count, total and self time
+    (total less the time of the spans nested in them)."""
+    spans = [ev for ev in events if ev.get("ph") == "X"]
+    nested: Dict[int, float] = defaultdict(float)
+    for ev in spans:
+        parent = (ev.get("args") or {}).get("parent")
+        if parent is not None:
+            nested[parent] += ev.get("dur", 0.0)
     agg: Dict[str, Dict[str, float]] = defaultdict(
-        lambda: {"n": 0, "total_us": 0.0})
-    for ev in events:
-        if ev.get("ph") == "X":
-            a = agg[ev["name"]]
-            a["n"] += 1
-            a["total_us"] += ev.get("dur", 0.0)
+        lambda: {"n": 0, "total_us": 0.0, "self_us": 0.0})
+    for ev in spans:
+        a = agg[ev["name"]]
+        dur = ev.get("dur", 0.0)
+        a["n"] += 1
+        a["total_us"] += dur
+        a["self_us"] += dur - nested.get((ev.get("args") or {}).get("id"),
+                                         0.0)
     return agg
 
 
@@ -96,12 +110,12 @@ def main(argv=None) -> int:
 
     print(f"{len(events)} events")
     print("\n== per-phase span breakdown ==")
-    print(f"{'span':<16}{'count':>8}{'total':>14}{'mean':>14}")
+    print(f"{'span':<16}{'count':>8}{'total':>14}{'self':>14}{'mean':>14}")
     agg = phase_breakdown(events)
-    for name in sorted(agg, key=lambda n: -agg[n]["total_us"]):
+    for name in sorted(agg, key=lambda n: -agg[n]["self_us"]):
         a = agg[name]
         print(f"{name:<16}{int(a['n']):>8}{_ms(a['total_us']):>14}"
-              f"{_ms(a['total_us'] / a['n']):>14}")
+              f"{_ms(a['self_us']):>14}{_ms(a['total_us'] / a['n']):>14}")
 
     reqs = {rid: r for rid, r in per_request(events).items()
             if r["wall_us"] is not None}
