@@ -480,6 +480,16 @@ class PagedServeEngine:
                 ln[i] = self.cache.seqs[req.eid].length
         return ln
 
+    def _note_live_pages(self, rows: np.ndarray) -> None:
+        """Telemetry of a decode dispatch whose attention reads `rows`
+        KV rows per lane: the pages they fill, against every table
+        entry."""
+        if self._paged_keys:
+            ps = self.cache.page_size
+            self.telemetry.attn_pages(
+                int(((rows + ps - 1) // ps).sum()),
+                self.max_batch * self.cache.max_pages)
+
     def _sample_rows(self, rows: jax.Array) -> np.ndarray:
         """rows: (max_batch, vocab) -> (max_batch,) tokens, per-lane
         sampling params, PRNG key threaded through the engine."""
@@ -774,6 +784,7 @@ class PagedServeEngine:
                 tokens[i, 0] = req.out_tokens[-1]
                 n_new[i] = 1
             tables, lens = self._tables(), self._lengths()
+            self._note_live_pages(lens + n_new)
         logits, dt = self._dispatch(self._step_fn, "decode_step",
                                     self._lane_args(ready), tokens, tables,
                                     lens, n_new)
@@ -869,6 +880,8 @@ class PagedServeEngine:
         plain = all(nd == 0 for _, nd in ready)
         step_fn = self._step_fn if plain else spec.verify_fn
         step_tokens = tokens[:, :1] if plain else tokens
+        self._note_live_pages(lengths + (n_new if plain
+                                         else step_tokens.shape[1]))
         lanes_idx = [i for i, _ in ready]
         logits, dt = self._dispatch(
             step_fn, "spec_verify",

@@ -160,8 +160,9 @@ class PagedKVCache:
 
     `pools` is the model's paged cache pytree (per-layer page pools);
     `table_for` assembles the padded (max_pages,) block-table row a lane
-    feeds to `DecoderLM.paged_step`.  Page 0 pads unused table entries —
-    padded slots are masked by length, never read into scores.
+    feeds to `DecoderLM.paged_step`.  Page 0 pads unused table entries:
+    the paged attention kernel never reads an entry past a lane's live
+    pages, and the jnp reference gathers and masks them.
 
     When `prefix_index` is attached (serve/prefix.py), admission can
     adopt trie-resident prompt pages (`seq.length` starts past them) and

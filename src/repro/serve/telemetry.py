@@ -169,6 +169,8 @@ class Telemetry:
         self.prefill_tokens_skipped = 0   # prompt tokens never prefilled
         self.fork_admissions = 0     # lanes admitted via PagedKVCache.fork
         self.cancelled = 0           # requests aborted before completion
+        self.attn_share_sum = 0.0    # live page shares of decode calls
+        self.attn_dispatches = 0
         self.itl_samples = _Window(MAX_ITL_SAMPLES)  # emitted-token gaps
         self.t_start: Optional[float] = None
         self.t_end: Optional[float] = None
@@ -280,6 +282,13 @@ class Telemetry:
             if family is not None:
                 self.decode_family = family
 
+    def attn_pages(self, live: int, slots: int):
+        """One decode dispatch's paged-attention walk: its lanes hold
+        `live` KV pages out of the `slots` = max_batch x max_pages
+        block-table entries (what a walk of every entry would read)."""
+        self.attn_share_sum += live / slots
+        self.attn_dispatches += 1
+
     def spec(self, drafted: int, accepted: int):
         """One verify step's ledger: `drafted` tokens proposed across
         the batch, `accepted` of them kept by the target."""
@@ -369,6 +378,9 @@ class Telemetry:
             "state_slot_occupancy_peak":
                 self.state_occupancy_samples.peak(),
             "batch_mean": self.batch_samples.mean(0.0),
+            **({"attn_live_page_share":
+                self.attn_share_sum / self.attn_dispatches}
+               if self.attn_dispatches else {}),
             **({f"lane_steps_{self.decode_family}":
                 float(self.decode_lane_steps)}
                if self.decode_family is not None else {}),

@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels.cim_gemv import cim_gemv
 from repro.kernels.flash_decode import flash_decode
-from repro.kernels.paged_flash_decode import (paged_flash_decode,
+from repro.kernels.paged_flash_decode import (pages_per_block,
+                                              paged_flash_decode,
                                               paged_flash_verify)
 from repro.kernels.ref import (ref_flash_decode, ref_paged_decode,
                                ref_paged_verify, ref_qmatmul,
@@ -63,19 +64,90 @@ def test_flash_decode_sweep(S, block_s, window, cap, pos_frac):
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
 
 
-@pytest.mark.parametrize("page_size,max_pages,window,cap", [
-    (16, 8, 0, 0.0),
-    (32, 4, 0, 0.0),
-    (16, 8, 40, 0.0),
-    (16, 8, 0, 30.0),
-    (8, 16, 24, 50.0),
-])
-def test_paged_flash_decode_sweep(page_size, max_pages, window, cap):
+def _edge_pool(rows, s, g, ps, mp, kv, rng, qpk=4, hd=64):
+    """q, pools and tables for lanes holding `rows` KV rows each, with
+    s query positions: tables as the engine builds them (shuffled live
+    pages, then page 0); the kernel's pools and scales hold NaN in page
+    0, which no lane owns, so reading a padded entry poisons the
+    output.  Returns (q, kernel pools, oracle pools, tables); pools are
+    (k, v, k_scales, v_scales), scales None for float pools."""
+    b = len(rows)
+    n_pages = b * mp + 1
+    q = jnp.asarray(rng.standard_normal((b, s, g, qpk, hd)), jnp.float32)
+    shape = (n_pages, g, ps, hd)
+    if kv == "int8":
+        k, v = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                for _ in range(2))
+        ks, vs = (jnp.asarray(rng.uniform(0.5, 1.5, shape[:3]) / 127,
+                              jnp.float32) for _ in range(2))
+        oracle = (k, v, ks, vs)
+        kernel = (k, v, ks.at[0].set(jnp.nan), vs.at[0].set(jnp.nan))
+    else:
+        k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                for _ in range(2))
+        oracle = (k, v, None, None)
+        kernel = (k.at[0].set(jnp.nan), v.at[0].set(jnp.nan), None, None)
+    tables = np.zeros((b, mp), np.int32)
+    ids = rng.permutation(np.arange(1, n_pages))
+    for i, n in enumerate(rows):
+        live = -(-n // ps)
+        tables[i, :live] = ids[i * mp:i * mp + live]
+    return q, kernel, oracle, jnp.asarray(tables)
+
+
+def _edge_rows(ps, mp, g, kv):
+    """KV rows at 1, ps - 1, ps, ps + 1, one block of the kernel - 1,
+    + 0, + 1, and the whole table."""
+    blk = ps * pages_per_block(ps, mp, g, 64, 1 if kv == "int8" else 4)
+    assert blk < mp * ps, "the table must span more than one block"
+    return (1, ps - 1, ps, ps + 1, blk - 1, blk, blk + 1, mp * ps)
+
+
+_DECODE_CASES = [
+    (16, 8, 0, 0.0, None),
+    (32, 4, 0, 0.0, None),
+    (16, 8, 40, 0.0, None),
+    (16, 8, 0, 30.0, None),
+    (8, 16, 24, 50.0, None),
+    (16, 40, 0, 0.0, (1, "f32")),
+    (16, 40, 0, 0.0, (2, "int8")),
+    (16, 40, 40, 30.0, (2, "int8")),
+    (16, 40, 0, 0.0, (10, "int8")),
+]
+
+
+def _case_id(case):
+    ps, mp, window, cap, edge = case
+    tag = f"-g{edge[0]}-{edge[1]}-edges" if edge else ""
+    return f"{ps}-{mp}-{window}-{cap}{tag}"
+
+
+@pytest.mark.parametrize("page_size,max_pages,window,cap,edge",
+                         _DECODE_CASES, ids=map(_case_id, _DECODE_CASES))
+def test_paged_flash_decode_sweep(page_size, max_pages, window, cap, edge):
     """Block-table kernel vs the gather oracle, shuffled page layouts and
-    ragged per-sequence lengths."""
+    ragged per-sequence lengths.  Edge cases: lengths at page and block
+    boundaries, an idle lane (finite zero output), g in {1, 2, 10},
+    int8 pools with f32 scales, and padded table entries never read."""
+    rng = np.random.default_rng(0)
+    if edge is not None:              # ... and an idle lane (length 0)
+        g, kv = edge
+        lens = _edge_rows(page_size, max_pages, g, kv) + (0,)
+        q, kern, orc, tables = _edge_pool(lens, 1, g, page_size, max_pages,
+                                          kv, rng)
+        q, lengths = q[:, 0], jnp.asarray(lens, jnp.int32)
+        ref = ref_paged_decode(q, *orc[:2], tables, lengths, window, cap,
+                               *orc[2:])
+        out = paged_flash_decode(q, *kern[:2], tables, lengths,
+                                 window=window, attn_cap=cap,
+                                 interpret=True, k_scales=kern[2],
+                                 v_scales=kern[3])
+        live = np.asarray(lens) > 0
+        assert float(jnp.max(jnp.abs(out - ref)[live])) < 1e-5
+        assert float(jnp.max(jnp.abs(out[~live]))) == 0.0
+        return
     b, g, qpk, hd = 3, 2, 4, 64
     n_pages = b * max_pages
-    rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, g, qpk, hd)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((n_pages, g, page_size, hd)),
                      jnp.float32)
@@ -91,19 +163,44 @@ def test_paged_flash_decode_sweep(page_size, max_pages, window, cap):
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
 
 
-@pytest.mark.parametrize("s,page_size,max_pages,window,cap", [
-    (4, 16, 8, 0, 0.0),
-    (5, 8, 16, 0, 0.0),
-    (3, 16, 8, 24, 0.0),
-    (4, 16, 8, 0, 30.0),
-    (2, 8, 16, 12, 50.0),
-])
-def test_paged_flash_verify_sweep(s, page_size, max_pages, window, cap):
+_VERIFY_CASES = [
+    (4, 16, 8, 0, 0.0, None),
+    (5, 8, 16, 0, 0.0, None),
+    (3, 16, 8, 24, 0.0, None),
+    (4, 16, 8, 0, 30.0, None),
+    (2, 8, 16, 12, 50.0, None),
+    (4, 16, 40, 0, 0.0, (2, "f32")),
+    (4, 16, 40, 0, 0.0, (1, "int8")),
+    (4, 16, 40, 0, 0.0, (10, "int8")),
+]
+
+
+@pytest.mark.parametrize("s,page_size,max_pages,window,cap,edge",
+                         _VERIFY_CASES,
+                         ids=[f"{c[0]}-" + _case_id(c[1:])
+                              for c in _VERIFY_CASES])
+def test_paged_flash_verify_sweep(s, page_size, max_pages, window, cap,
+                                  edge):
     """Multi-query verify kernel vs the gather oracle: shuffled page
-    layouts, ragged base lengths, every intra-window causal horizon."""
+    layouts, ragged base lengths, every intra-window causal horizon;
+    edge cases as `test_paged_flash_decode_sweep`'s."""
+    rng = np.random.default_rng(0)
+    if edge is not None:       # windows that end at `_edge_rows`, and
+        g, kv = edge           # one that starts at 0
+        rows = _edge_rows(page_size, max_pages, g, kv)[1:] + (s,)
+        q, kern, orc, tables = _edge_pool(rows, s, g, page_size, max_pages,
+                                          kv, rng)
+        lengths = jnp.asarray(rows, jnp.int32) - s
+        ref = ref_paged_verify(q, *orc[:2], tables, lengths, window, cap,
+                               *orc[2:])
+        out = paged_flash_verify(q, *kern[:2], tables, lengths,
+                                 window=window, attn_cap=cap,
+                                 interpret=True, k_scales=kern[2],
+                                 v_scales=kern[3])
+        assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+        return
     b, g, qpk, hd = 3, 2, 4, 64
     n_pages = b * max_pages
-    rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, s, g, qpk, hd)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((n_pages, g, page_size, hd)),
                      jnp.float32)

@@ -412,3 +412,41 @@ def test_deadline_rejection_and_streaming_callback():
     eng.run([ok, late])
     assert ok.done and got == ok.out_tokens, "streaming callback fires"
     assert late.rejected and late.out_tokens == []
+
+
+def test_attn_live_page_share_counts_the_pages_each_decode_holds():
+    """`attn_live_page_share` is the mean over decode dispatches of
+    sum ceil(total / page_size) / (max_batch x max_pages), from the
+    lengths each dispatch hands the kernel; absent before any decode."""
+    from repro.obs import prometheus_text
+    from repro.serve import ServeConfig
+    model, params = _model()
+    eng = PagedServeEngine(model, params, ServeConfig(
+        max_batch=3, max_seq=64, page_size=8, prefill_chunk=8))
+    assert "attn_live_page_share" not in eng.summary()
+    shares = []
+    dispatch = eng._dispatch
+
+    def spy(fn, name, args, tokens, tables, lengths, n_new):
+        if name == "decode_step":
+            total = lengths + n_new
+            shares.append(sum(-(-int(t) // 8) for t in total) / (3 * 8))
+        return dispatch(fn, name, args, tokens, tables, lengths, n_new)
+
+    eng._dispatch = spy
+    rng = np.random.default_rng(3)
+    reqs = [ServeRequest(prompt=rng.integers(0, 64, n).astype(np.int32),
+                         max_new_tokens=m, rid=i)
+            for i, (n, m) in enumerate([(15, 4), (3, 12), (9, 9)])]
+    eng.submit(reqs[0])
+    eng.step()               # the first of two prefill chunks, no decode
+    assert shares == [] and "attn_live_page_share" not in eng.summary()
+    for r in reqs[1:]:
+        eng.submit(r)
+    while eng.busy:
+        eng.step()
+    assert len(shares) > 5 and min(shares) < max(shares) < 1
+    got = eng.summary()["attn_live_page_share"]
+    assert got == pytest.approx(float(np.mean(shares)), rel=1e-12)
+    assert "repro_engine_attn_live_page_share" in prometheus_text(
+        {"engine": eng.summary()})

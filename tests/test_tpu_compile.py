@@ -2,7 +2,8 @@
 
 Compiles against a described `v5e:2x2` topology (no chip attached) at
 qwen2.5-3b widths: g=2 KV heads, hd=128, page 16, d_model 2048,
-d_ff 11008, vocab 151936, group 128.  Interpret-mode tests cannot see the
+d_ff 11008, vocab 151936, group 128 — and the paged decode kernel at the
+benchmark cells' shapes too.  Interpret-mode tests cannot see the
 TPU's block-shape and Mosaic lowering rules; these compiles can.  Every
 test asserts the kernel is in the compiled program (`tpu_custom_call`).
 
@@ -65,15 +66,35 @@ def _i32(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_flash_decode_compiles(one_chip, kv):
+# (b, max_seq, g, qpk): the smoke shape at qwen2.5-3b widths, then the
+# benchmark cells' decode attention — 32 lanes of 2,048 rows at qwen's
+# and phi3-medium's head counts, and the chat cell's 16 lanes of 1,280
+_DECODE_SHAPES = {"": (B, MAX_SEQ, G, QPK), "-qwen-batch": (32, 2048, 2, 8),
+                  "-phi3-batch": (32, 2048, 10, 4),
+                  "-qwen-chat": (16, 1280, 2, 8)}
+
+
+_DECODE_CASES = [(kv, tag) for tag in _DECODE_SHAPES for kv in ("bf16", "int8")]
+
+
+@pytest.mark.parametrize("kv,shape", _DECODE_CASES,
+                         ids=[kv + tag for kv, tag in _DECODE_CASES])
+def test_paged_flash_decode_compiles(one_chip, kv, shape):
+    """The block size and VMEM budget the kernel derives from each shape
+    pass Mosaic's rules."""
     from repro.kernels.paged_flash_decode import paged_flash_decode
-    k, v, ks, vs = _kv(one_chip, kv)
-    q = jax.ShapeDtypeStruct((B, G, QPK, HD), jnp.bfloat16, sharding=one_chip)
-    _compile(lambda q, k, v, t, n, ks, vs: paged_flash_decode(
+    b, max_seq, g, qpk = _DECODE_SHAPES[shape]
+    mp = max_seq // PS
+    dt = jnp.int8 if kv == "int8" else jnp.bfloat16
+    pool = jax.ShapeDtypeStruct((b * mp, g, PS, HD), dt, sharding=one_chip)
+    sc = (jax.ShapeDtypeStruct((b * mp, g, PS), jnp.float32,
+                               sharding=one_chip) if kv == "int8" else None)
+    q = jax.ShapeDtypeStruct((b, g, qpk, HD), jnp.bfloat16, sharding=one_chip)
+    text = _compile(lambda q, k, v, t, n, ks, vs: paged_flash_decode(
         q, k, v, t, n, k_scales=ks, v_scales=vs),
-        q, k, v, _i32((B, MAX_SEQ // PS), one_chip), _i32((B,), one_chip),
-        ks, vs)
+        q, pool, pool, _i32((b, mp), one_chip), _i32((b,), one_chip),
+        sc, sc)
+    assert "paged_flash_attention" in text
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
