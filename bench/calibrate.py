@@ -45,11 +45,10 @@ def sweep(cell, rates, seconds, seed) -> None:
     import endtoend
     import harness
     import program
-    import weights as W
     devs = device.check(cell["chips"])
     harness.enable_compile_cache()
     cfg, traffic = cells.config(cell["config"]), cells.traffic(cell["traffic"])
-    vocab = W.dims(cfg)["v"]
+    vocab = cells.family(cfg).dims(cfg)["v"]
     gen = cells.generator(traffic["generator"])
     model, engines = program.build(cfg, traffic["engine"], seed)
     program.warm_up(engines, traffic["engine"], vocab)

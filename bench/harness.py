@@ -35,7 +35,6 @@ import cells
 import device
 import endtoend
 import program
-import weights as W
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CLIENT = os.path.join(HERE, "client.py")
@@ -257,8 +256,9 @@ def correctness(cfg: Dict, seed: int, records: List[Dict],
     the control's tokens (at each served position, the first choice of
     the reference one precision step lower) stand in the program's place
     and go through the same comparison; the program's own reading is
-    kept beside it."""
-    import reference.dense_gqa as R
+    kept beside it.  The reference is the one the configuration's family
+    names."""
+    R = cells.reference(cells.family(cfg))
     pool = [r for r in records if len(r["tokens"]) >= 2
             and not endtoend.refused(r)]
     if not pool:
@@ -309,7 +309,8 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, lim: Dict, seed: int,
     compiles = CompileCount()
     if require_tpu:
         enable_compile_cache()
-    dims = W.dims(cfg)
+    family = cells.family(cfg)
+    dims = family.dims(cfg)
     gen = cells.generator(traffic["generator"])
     schedule = gen.build(traffic, seed, dims["v"], seconds)
     geom = traffic["engine"]
@@ -349,7 +350,7 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, lim: Dict, seed: int,
             values["output_tok_s"] = e2e["tokens"] / seconds
         wanted = end_to_end
     else:
-        ctx = {"cfg": cfg, "dims": dims, "engine": geom,
+        ctx = {"cfg": cfg, "family": family, "dims": dims, "engine": geom,
                "kv_bytes": 1 if kv_name == "int8" else 2,
                "peaks": device.peaks(devs[0].device_kind if require_tpu
                                      else OFF_CHIP_PEAKS),

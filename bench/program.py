@@ -1,10 +1,12 @@
 """The system under test, built from a configuration file.
 
-This is the one module of a run that imports the program
-(`repro`).  It maps a configuration's Hugging Face sizes onto the
-program's model config, hands the program the benchmark's packed int4
-weights as its own `QTensor`s, and builds the engines the way the
-launcher does (`repro.launch.serve.build_engines`): a `FleetRouter`
+This module and the families' `model_config` and `program_params`
+(`bench/families/<family>.py`) are the only code of a run that imports
+the program (`repro`).  The configuration's family maps its sizes onto
+the program's model config and lays the benchmark's packed int4 weights
+out as the program's parameter tree of `QTensor`s; this module checks
+that tree against the model's own specs and builds the engines the way
+the launcher does (`repro.launch.serve.build_engines`): a `FleetRouter`
 over `PagedServeEngine`s, served by an in-process `Gateway`.
 """
 from __future__ import annotations
@@ -14,57 +16,30 @@ from typing import Dict
 
 import jax
 
+import cells
 import weights as W
 
 
-def model_config(cfg: Dict):
-    """The program's ModelConfig for a configuration file: the registry
-    entry named by `arch`, with every size set from the file."""
-    from repro.configs import get_config
-    s = W.dims(cfg)
-    return get_config(cfg["arch"]).replace(
-        n_layers=s["L"], d_model=s["d"], n_heads=s["h"], n_kv_heads=s["g"],
-        head_dim=s["hd"], d_ff=s["f"], vocab=s["v"], qkv_bias=s["bias"],
-        tie_embeddings=s["tied"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), dtype="bfloat16", remat=False)
+def qtensor(codes, scales, shape, axis: int = -2):
+    """The program's `QTensor` over packed int4 codes and their scales
+    (`weights.packed`), grouped along `axis`."""
+    from repro.quant.qarray import QTensor
+    return QTensor(data=codes, scales=scales, bits=4, group=W.GROUP,
+                   axis=axis, orig_shape=shape)
 
 
 def make_leaves(cfg: Dict, seed: int) -> Dict:
     """Every weight from the seed, in one device program."""
-    fn = jax.jit(functools.partial(W.all_leaves, cfg))
+    fn = jax.jit(functools.partial(cells.family(cfg).all_leaves, cfg))
     return jax.block_until_ready(fn(W.root_key(seed)))
 
 
-def program_params(cfg: Dict, leaves: Dict, model) -> Dict:
-    """The program's parameter tree over the benchmark's leaves, checked
+def param_tree(cfg: Dict, leaves: Dict, model) -> Dict:
+    """The family's parameter tree over the benchmark's leaves, checked
     against the model's own parameter specs."""
-    from repro.quant.qarray import QTensor
-    s = W.dims(cfg)
-    L = s["L"]
-
-    def mat(codes, scales, shape):
-        return QTensor(data=codes, scales=scales, bits=4, group=W.GROUP,
-                       axis=-2, orig_shape=shape)
-
-    lay = leaves["layers"]
-    shp = W.matrix_shapes(cfg)
-    attn = {k: mat(*lay[k], (L, *shp[k])) for k in ("wq", "wk", "wv", "wo")}
-    if s["bias"]:
-        attn.update({k: lay[k] for k in ("bq", "bk", "bv")})
-    ffn = {k: mat(*lay[k], (L, *shp[k])) for k in ("w_gate", "w_up",
-                                                    "w_down")}
-    codes, scales = leaves["embed"]
-    params = {
-        "embed": QTensor(data=codes, scales=scales, bits=4, group=W.GROUP,
-                         axis=-1, orig_shape=(s["v"], s["d"])),
-        "ln_final": {"scale": leaves["ln_final"]},
-        "blocks": {"ln_attn": {"scale": lay["ln_attn"]}, "attn": attn,
-                   "ln_ffn": {"scale": lay["ln_ffn"]}, "ffn": ffn},
-    }
-    if not s["tied"]:
-        params["head"] = mat(*leaves["head"], (s["d"], s["v"]))
-    _check_against_specs(params, model.param_specs())
-    return params
+    tree = cells.family(cfg).program_params(cfg, leaves, model)
+    _check_against_specs(tree, model.param_specs())
+    return tree
 
 
 def _check_against_specs(params, specs) -> None:
@@ -99,9 +74,9 @@ def build(cfg: Dict, engine: Dict, seed: int):
     """(model, engines) serving the seed's weights."""
     from repro.launch.serve import build_engines
     from repro.models import DecoderLM
-    model = DecoderLM(model_config(cfg))
+    model = DecoderLM(cells.family(cfg).model_config(cfg))
     leaves = make_leaves(cfg, seed)
-    params = program_params(cfg, leaves, model)
+    params = param_tree(cfg, leaves, model)
     del leaves
     return model, build_engines(model, params, serve_config(cfg, engine))
 

@@ -5,174 +5,53 @@ reads KV head h // (heads / kv_heads)), optional q/k/v biases, rotary
 embedding over split halves, causal softmax; RMSNorm, SwiGLU
 (silu(x W_gate) * (x W_up)) W_down; a final RMSNorm and the head (the
 embedding's transpose when tied).  Weights are the int4 codes times their
-scales from `weights.py`, in float32; every matmul runs at the highest
-precision.  It imports nothing of the program under test and takes
-nothing it made: the weights are regenerated here, one layer at a time,
-from the seed.
+scales from the `dense_gqa` family, in float32; every matmul runs at the
+highest precision.  It imports nothing of the program under test and
+takes nothing it made: the weights are regenerated here, one layer at a
+time, from the seed (`reference/common.py`).
 
-`served_logits` teacher-forces each prompt with its served tokens and
-returns the logits at the positions that produced them.  With
-`control=True` it computes one precision step below each precision the
-configuration states: every matmul input is rounded to float8 e4m3
+`common.served_logits` teacher-forces each prompt with its served tokens
+through `_block` and returns the logits at the positions that produced
+them.  With `control=True` it computes one precision step below each
+precision the configuration states: every matmul input is rounded to float8 e4m3
 under a per-row scale (the served activations are bfloat16), and K and
 V to int4 under a per-row, per-head scale (the served KV is int8).
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence
+from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+import cells
 import weights as W
-
-BUCKET = 256            # sequence lengths are padded to a multiple of it
-_FP8_MAX = 448.0
-
-
-def _fp8(x: jax.Array) -> jax.Array:
-    """Round each row to float8 e4m3 under a per-row scale."""
-    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    s = _FP8_MAX / jnp.maximum(amax, 1e-30)
-    return (x * s).astype(jnp.float8_e4m3fn).astype(jnp.float32) / s
-
-
-def _int4(x: jax.Array) -> jax.Array:
-    """Round each row (last axis) to symmetric int4 under its own scale."""
-    s = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 7.0
-    s = jnp.maximum(s, 1e-30)
-    return jnp.clip(jnp.round(x / s), -7.0, 7.0) * s
-
-
-def _same(x: jax.Array) -> jax.Array:
-    return x
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    """x (T, heads, hd); rotation of the two halves of each head."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
-
-
-def _frozen(cfg: Dict) -> tuple:
-    """The scalar entries of a config, hashable for a static argument."""
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, bool, str))))
+from reference import common as C
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "control"))
 def _block(x, lw, cfg, control: bool):
     """One decoder layer over one padded sequence x (T, d)."""
     c = dict(cfg)
-    s = W.dims(c)
+    fam = cells.family(c)
+    s = fam.dims(c)
     eps, theta = c["rms_norm_eps"], c["rope_theta"]
-    rnd = _fp8 if control else _same
-    kv = _int4 if control else _same
-    mat = {k: W.unpack(*lw[k]) for k in W.LAYER_MATRICES}
-    T = x.shape[0]
-    h = rnd(_rms(x, lw["ln_attn"].astype(jnp.float32), eps))
-    q, k, v = h @ mat["wq"], h @ mat["wk"], h @ mat["wv"]
-    if s["bias"]:
-        q = q + lw["bq"].astype(jnp.float32)
-        k = k + lw["bk"].astype(jnp.float32)
-        v = v + lw["bv"].astype(jnp.float32)
-    pos = jnp.arange(T)
-    q = _rope(q.reshape(T, s["h"], s["hd"]), pos, theta)
-    k = kv(_rope(k.reshape(T, s["g"], s["hd"]), pos, theta))
-    v = kv(v.reshape(T, s["g"], s["hd"]))
-    rep = s["h"] // s["g"]
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    sc = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(s["hd"]))
-    causal = pos[:, None] >= pos[None, :]
-    p = jax.nn.softmax(jnp.where(causal[None], sc, -1e30), axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, v).reshape(T, s["h"] * s["hd"])
-    x = x + rnd(o) @ mat["wo"]
-    h = rnd(_rms(x, lw["ln_ffn"].astype(jnp.float32), eps))
+    rnd = C.fp8 if control else C.same
+    mat = {k: W.unpack(*lw[k]) for k in fam.LAYER_MATRICES}
+    x = x + C.gqa_attention(x, lw, mat, s, eps, theta, control)
+    h = rnd(C.rms(x, lw["ln_ffn"].astype(jnp.float32), eps))
     g, u = h @ mat["w_gate"], h @ mat["w_up"]
     return x + rnd(jax.nn.silu(g) * u) @ mat["w_down"]
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "control"))
-def _head(h, gl, cfg, control: bool):
-    """Logits (m, V) of final hidden states h (m, d)."""
-    c = dict(cfg)
-    rnd = _fp8 if control else _same
-    h = rnd(_rms(h, gl["ln_final"].astype(jnp.float32), c["rms_norm_eps"]))
-    if W.dims(c)["tied"]:
-        codes, scales = gl["embed"]
-        head = W.unpack(codes.T, scales.T)                  # (d, V)
-    else:
-        head = W.unpack(*gl["head"])
-    return h @ head
-
-
-@jax.jit
-def _embed(gl, ids):
-    codes, scales = gl["embed"]                 # (V, d/2), (V, d/GROUP)
-    return jax.vmap(lambda c, s: W.unpack(c[:, None], s[:, None])[:, 0])(
-        codes[ids], scales[ids])
-
-
-def served_logits(cfg: Dict, seed: int, seqs: Sequence[Dict],
-                  control: bool = False) -> List[np.ndarray]:
-    """For each {"prompt": [...], "served": [...]}: the reference logits
-    (n_served, V) at the positions that produced each served token."""
-    key = _frozen(cfg)
-    root = W.root_key(seed)
-    with jax.default_matmul_precision("highest"):
-        gl = jax.jit(functools.partial(W.global_leaves, cfg))(root)
-        xs = []
-        for q in seqs:
-            toks = np.concatenate([np.asarray(q["prompt"], np.int32),
-                                   np.asarray(q["served"][:-1], np.int32)])
-            ids = np.zeros(-(-len(toks) // BUCKET) * BUCKET, np.int32)
-            ids[:len(toks)] = toks
-            xs.append(_embed(gl, jnp.asarray(ids)))
-        layer_fn = jax.jit(functools.partial(W.layer_leaves, cfg))
-        for layer in range(W.dims(cfg)["L"]):
-            lw = layer_fn(root, jnp.int32(layer))
-            xs = [_block(x, lw, key, control) for x in xs]
-            del lw
-        out = []
-        for x, q in zip(xs, seqs):
-            p, n = len(q["prompt"]), len(q["served"])
-            out.append(np.asarray(_head(x[p - 1:p - 1 + n], gl, key,
-                                        control)))
-        return out
-
-
-def _widest(ref: List[np.ndarray], picks: List[np.ndarray]) -> Dict:
-    gaps = np.concatenate([lg.max(-1) - lg[np.arange(len(t)), t]
-                           for lg, t in zip(ref, picks)])
-    return {"widest_gap": float(gaps.max()), "tokens": int(gaps.size),
-            "mean_gap": float(gaps.mean())}
 
 
 def served_gap(cfg: Dict, seed: int, seqs: Sequence[Dict]) -> Dict:
     """How far each served token's reference logit lies below the best
     at its position; `widest_gap` is what decides `correct`."""
-    ref = served_logits(cfg, seed, seqs)
-    return _widest(ref, [np.asarray(q["served"]) for q in seqs])
+    return C.served_gap(cfg, seed, seqs, _block)
 
 
 def control_gap(cfg: Dict, seed: int, seqs: Sequence[Dict]) -> Dict:
-    """The same reading for the control: at each served position, the
-    token the lower-precision reference ranks first, judged by the
-    float32 one.
-    Returns the program's reading too (`served`), from the same
-    float32 logits."""
-    ref = served_logits(cfg, seed, seqs)
-    ctl = served_logits(cfg, seed, seqs, control=True)
-    return {"served": _widest(ref, [np.asarray(q["served"]) for q in seqs]),
-            "control": _widest(ref, [lc.argmax(-1) for lc in ctl])}
+    """The same reading for the control (float8 matmul inputs, int4 K
+    and V), with the program's own reading beside it (`served`)."""
+    return C.control_gap(cfg, seed, seqs, _block)

@@ -54,7 +54,6 @@ def main(argv=None) -> int:
 
     import cells
     import program
-    import weights as W
     from repro.kernels import ops
     from repro.models import DecoderLM
     from repro.models.common import spec_structs
@@ -65,6 +64,7 @@ def main(argv=None) -> int:
     ops._interpret = lambda: False
     cell = cells.workload(args.workload)
     cfg = cells.config(cell["config"])
+    fam = cells.family(cfg)
     geom = cells.traffic(cell["traffic"])["engine"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -76,12 +76,12 @@ def main(argv=None) -> int:
 
     out = {}
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
-    make = jax.jit(functools.partial(W.all_leaves, cfg))
+    make = jax.jit(functools.partial(fam.all_leaves, cfg))
     out["weights"] = _analysis(make.lower(key).compile())
-    leaves = jax.eval_shape(functools.partial(W.all_leaves, cfg),
+    leaves = jax.eval_shape(functools.partial(fam.all_leaves, cfg),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
-    model = DecoderLM(program.model_config(cfg))
-    params = put(program.program_params(cfg, leaves, model))
+    model = DecoderLM(fam.model_config(cfg))
+    params = put(program.param_tree(cfg, leaves, model))
     b, ps = geom["max_batch"], geom["page_size"]
     n_pages = b * geom["max_seq"] // ps
     kv = {"int8": jnp.int8, "bf16": jnp.bfloat16}[cfg["kv_dtype"]]

@@ -1,6 +1,8 @@
 """BENCHMARK.json and the files it names hold together: every cell finds
 its configuration, traffic and limits, every per-layer metric its
-reader, and a run with no chip prints no result."""
+reader, every configuration a family that exposes the whole contract,
+and a run with no chip prints no result."""
+import json
 import os
 import re
 import subprocess
@@ -30,6 +32,28 @@ def test_names_and_files():
         cells.generator(t["generator"])
         lim = cells.limits(w["name"])
         assert lim["widest_gap"]["limit"] > 0
+
+
+FAMILY_CONTRACT = ("dims", "all_leaves", "layer_leaves", "global_leaves",
+                   "model_config", "program_params", "decode_work")
+TESTS = os.path.join(cells.HERE, "tests")
+
+
+@pytest.mark.parametrize("where", [c["file"] for c in BENCH["configs"]] + [
+    "bench/tests/tiny_config.json", "bench/tests/tiny_moe_config.json"])
+def test_every_configuration_names_a_family_with_the_whole_contract(
+        where, monkeypatch):
+    if where.endswith("tiny_moe_config.json"):
+        monkeypatch.setattr(cells, "FAMILIES",
+                            os.path.join(TESTS, "fixture_families"))
+        monkeypatch.setattr(cells, "REFERENCES",
+                            os.path.join(TESTS, "fixture_references"))
+    with open(os.path.join(cells.ROOT, where)) as f:
+        fam = cells.family(json.load(f))
+    for name in FAMILY_CONTRACT:
+        assert callable(getattr(fam, name)), name
+    ref = cells.reference(fam)
+    assert callable(ref.served_gap) and callable(ref.control_gap)
 
 
 def test_every_cell_reports_setup_another_metric_and_a_layer():

@@ -3,12 +3,12 @@ out by hand at qwen2.5-3b and phi3-medium shapes."""
 import pytest
 
 import cells
-import weights as W
-from work import decode_step, paged_flash_attention as attn
+from work import paged_flash_attention as attn
 from work import swiglu_qgemv as ffn
 
-QWEN = W.dims(cells.config("qwen2.5-3b-int4"))
-PHI3 = W.dims(cells.config("phi3-medium-14b-int4"))
+DENSE = cells.family(cells.config("qwen2.5-3b-int4"))
+QWEN = DENSE.dims(cells.config("qwen2.5-3b-int4"))
+PHI3 = DENSE.dims(cells.config("phi3-medium-14b-int4"))
 
 
 def test_dims_are_the_published_widths():
@@ -48,29 +48,29 @@ def test_swiglu_call_streams_both_packed_weights_once():
 def test_decode_step_bytes_and_flops():
     # qwen per layer: q 2048x2048, k and v 2048x256, o 2048x2048, gate,
     # up 2048x11008, down 11008x2048 -> 77_070_336 weights
-    assert sum(k * n for k, n in decode_step.layer_matrices(QWEN)) == \
+    assert sum(k * n for k, n in DENSE.matrix_shapes(QWEN).values()) == \
         77_070_336
-    assert decode_step.matmul_weights(QWEN) == \
+    assert DENSE.matmul_weights(QWEN) == \
         36 * 77_070_336 + 2048 * 151936
     # packed: half a byte per weight and an f32 scale per 128 rows and
     # column; the head once; bf16 norms (2 per layer + final) and biases
     layer = sum(k * n // 2 + 4 * (k // 128) * n
-                for k, n in decode_step.layer_matrices(QWEN))
+                for k, n in DENSE.matrix_shapes(QWEN).values())
     head = 2048 * 151936 // 2 + 4 * 16 * 151936
     small = 2 * (2 * 2048 * 36 + 2048) + 2 * (16 + 4) * 128 * 36
-    assert decode_step.streamed_bytes(QWEN) == 36 * layer + head + small
-    w = decode_step.work(QWEN, [10, 20], 3, 16, 1)
+    assert DENSE.streamed_bytes(QWEN) == 36 * layer + head + small
+    w = DENSE.decode_work(QWEN, [10, 20], 3, 16, 1)
     a = attn.total(QWEN, [10, 20], 1)
     new_rows = 2 * 2 * 2 * 132 * 36
-    assert w["bytes"] == 3 * (decode_step.streamed_bytes(QWEN)
+    assert w["bytes"] == 3 * (DENSE.streamed_bytes(QWEN)
                               + 4 * 16 * 151936) + a["bytes"] + new_rows
     assert w["flops"] == pytest.approx(
-        2 * 2 * decode_step.matmul_weights(QWEN) + a["flops"])
+        2 * 2 * DENSE.matmul_weights(QWEN) + a["flops"])
 
 
 def test_phi3_decode_step_streams_the_untied_head_not_the_embedding():
     layer = sum(k * n // 2 + 4 * (k // 128) * n
-                for k, n in decode_step.layer_matrices(PHI3))
+                for k, n in DENSE.matrix_shapes(PHI3).values())
     head = 5120 * 32064 // 2 + 4 * 40 * 32064
     small = 2 * (2 * 5120 * 10 + 5120)
-    assert decode_step.streamed_bytes(PHI3) == 10 * layer + head + small
+    assert DENSE.streamed_bytes(PHI3) == 10 * layer + head + small

@@ -20,14 +20,15 @@ def load(name):
 
 
 def run(loop="open", seed=2 ** 33 + 5, seconds=2.0, trace=False,
-        plant=None, control=False):
+        plant=None, control=False, config="tiny_config.json",
+        limits=LIMITS):
     import cells
     import harness
     bench = cells.benchmark()
     cell = ("qwen2.5-3b-int4.chat" if loop == "open"
             else "qwen2.5-3b-int4.batch")
     return harness.run_cell(
-        CELL, load("tiny_config.json"), load(f"tiny_{loop}.json"), LIMITS,
+        CELL, load(config), load(f"tiny_{loop}.json"), limits,
         seed, seconds, trace, per_layer=cells.per_layer(cell, bench),
         end_to_end=cells.end_to_end(cell, bench), t_process=time.monotonic(),
         require_tpu=False, plant=plant, control=control)
